@@ -66,6 +66,9 @@ def main() -> None:
     args = ap.parse_args()
     args.fast = not (args.standard or args.paper)
 
+    from repro.core import configure_compile_cache
+    configure_compile_cache()
+
     if args.alpha_backend:
         from repro.core import set_alpha_backend
         set_alpha_backend(args.alpha_backend)

@@ -5,12 +5,14 @@ NP-hardness reduction, and the collective planner on a synthetic train step.
 """
 import numpy as np
 
-from repro.core import (bna, dma_srt, fsp_to_coflow_job, gap_bounds,
-                        gap_instance, gap_optimal_schedule_length,
-                        verify_schedule, effective_size)
+from repro.core import (bna, configure_compile_cache, dma_srt,
+                        fsp_to_coflow_job, gap_bounds, gap_instance,
+                        gap_optimal_schedule_length, verify_schedule,
+                        effective_size)
 
 
 def main() -> None:
+    configure_compile_cache()
     # 1) BNA schedules any coflow in exactly its effective size (Lemma 1)
     rng = np.random.default_rng(0)
     d = rng.integers(0, 50, size=(6, 6)).astype(np.int64)

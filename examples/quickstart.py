@@ -6,11 +6,13 @@ SchedulerSession (the §VII-C.2 online protocol as an API).
   PYTHONPATH=src python examples/quickstart.py
 """
 from repro import scenarios
-from repro.core import (SchedulerSession, available_schedulers, plan,
-                        verify_schedule, workload_stats)
+from repro.core import (SchedulerSession, available_schedulers,
+                        configure_compile_cache, plan, verify_schedule,
+                        workload_stats)
 
 
 def main() -> None:
+    configure_compile_cache()
     # a Facebook-trace-calibrated workload: ~5 coflows per job, rooted-tree
     # dependencies (Hive/MapReduce-style stages). Gains grow with port count
     # and job count (paper Fig 6a) — benchmarks/run.py sweeps the full range,

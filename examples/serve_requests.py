@@ -7,11 +7,13 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.core import configure_compile_cache
 from repro.serve import Request, ServeConfig, ServingEngine
 from repro.train.step import init_params
 
 
 def main() -> None:
+    configure_compile_cache()
     cfg = get_config("qwen3-1.7b").smoke()
     params = init_params(cfg, jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)

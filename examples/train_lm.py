@@ -13,6 +13,7 @@ import argparse
 import tempfile
 
 from repro.configs import get_config
+from repro.core import configure_compile_cache
 from repro.data.pipeline import DataConfig
 from repro.ft import FTConfig, TrainRunner
 from repro.models.common import LayerSpec
@@ -27,6 +28,7 @@ def main() -> None:
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--batch", type=int, default=8)
     args = ap.parse_args()
+    configure_compile_cache()
 
     cfg = get_config("tinyllama-1.1b").replace(
         name="example-lm",

@@ -1,7 +1,7 @@
 """backend-dispatch: kernel implementations are reached only through the
 ``core/backend.py`` dispatch layer (``REPRO_ALPHA_BACKEND`` /
 ``REPRO_BNA_BACKEND`` / ``REPRO_PLAN_BACKEND``) so every call site gets
-the guard + numpy-fallback + cache behaviour for free.  Direct
+the backend resolution, int32 guards and caches for free.  Direct
 ``repro.kernels`` imports are allowed only in the dispatch layer itself,
 the jitted pipeline, the kernel packages, tests, and benchmarks."""
 from __future__ import annotations

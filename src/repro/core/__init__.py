@@ -4,7 +4,8 @@ primal-dual job ordering, G-DM / G-DM-RT, the O(m)Alg baseline, backfilling,
 the online driver, and the paper's workload/verification machinery."""
 
 from .backend import (bna_pieces_many, cache_stats, clear_caches,
-                      compute_alphas, group_block, grouping_prefix,
+                      compute_alphas, configure_compile_cache,
+                      group_block, grouping_prefix,
                       prefetch_bna, prefetch_plan,
                       set_alpha_backend, set_bna_backend, set_plan_backend,
                       use_alpha_backend, use_bna_backend, use_plan_backend)

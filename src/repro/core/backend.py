@@ -7,20 +7,21 @@ variables, selects how the engine's two hot paths execute:
 * **alpha backend** — ``merge_and_fix`` (timeline.py, Lemma 6 Steps 3-4)
   computes alpha_I per merged interval.  ``"numpy"`` runs the chunked
   prefix-sum oracle (`timeline._alphas_vectorized`); ``"pallas"`` routes
-  through the ``kernels/coflow_merge`` Pallas kernel (interpret mode on CPU,
-  compiled on TPU); ``"auto"`` picks pallas iff a TPU backend is attached.
-  Any kernel failure falls back to the numpy oracle (warned once) — the two
-  are bit-identical, so the fallback is safe.
+  through the ``kernels/coflow_merge`` Pallas kernel (Mosaic-compiled on a
+  TPU, interpret mode elsewhere); ``"auto"`` picks pallas iff
+  ``jax.default_backend()`` is a TPU.  The two are bit-identical.  A kernel
+  failure always raises: no backend falls back to another, so a run that
+  resolved pallas did its work on the kernel.
 
 * **BNA backend** — the batched matching layer (``core/matching.py``,
   ``bna_many``) vectorizes the multi-coflow BNA decomposition and
   dispatches its inner step per ``REPRO_BNA_BACKEND``: ``"numpy"`` runs the
   in-place vectorized step, ``"pallas"`` routes the same integer arithmetic
-  through the ``kernels/bna_step`` kernel (interpret mode on CPU, compiled
-  on TPU), ``"auto"`` picks pallas iff a TPU backend is attached.  The two
-  are bit-identical, so the auto fallback on kernel failure is safe (an
-  explicitly requested pallas backend propagates the error, mirroring the
-  alpha backend).
+  through the ``kernels/bna_step`` kernel (Mosaic-compiled on a TPU,
+  interpret mode elsewhere), ``"auto"`` picks pallas iff the backend is a
+  TPU.  Bit-identical; failures raise, as for the alpha backend.  A bucket
+  past the kernel's int32 range runs the numpy step (exact there), and
+  ``cache_stats()["launches"]`` counts only the kernel's real launches.
 
 * **BNA cache** — a bounded LRU keyed on ``(shape, dtype, bytes)`` of the
   demand, memoizing BNA decompositions (Algorithm 1).  Unlike the old
@@ -69,11 +70,12 @@ variables, selects how the engine's two hot paths execute:
   per-instance prefetch, the per-coflow edge-interval construction, and the
   Algorithm 5 ordering inputs through fixed-shape compiled XLA programs
   (bit-identical plans — all-integer arithmetic); ``"auto"`` picks jit iff
-  a TPU backend is attached (on CPU the compile latency only pays off for
+  the backend is a TPU (on CPU the compile latency only pays off for
   large instances, so it is opt-in there — same policy as the alpha/BNA
-  knobs).  A pipeline failure under ``auto`` falls back to the python path
-  with a one-time warning; an explicitly requested jit backend propagates
-  the error.
+  knobs).  A pipeline failure raises.  The only host path left under jit
+  is the per-bucket int32 range guard in ``core/pipeline.py``: a bucket
+  whose loads would overflow int32 decomposes on the numpy path, exactly,
+  and counts in ``cache_stats()["plan"]["compile"]["bucket_fallbacks"]``.
 
 Environment switches (read once at import; also settable in-process)::
 
@@ -94,13 +96,15 @@ from __future__ import annotations
 
 import os
 import sys
-import warnings
 from collections import OrderedDict
 from contextlib import contextmanager
 from dataclasses import dataclass
+from pathlib import Path
 from typing import Iterable
 
 import numpy as np
+
+from repro.kernels import launch_counts, reset_launch_counts
 
 __all__ = [
     "BackendConfig",
@@ -127,6 +131,7 @@ __all__ = [
     "cache_stats",
     "clear_caches",
     "no_caches",
+    "configure_compile_cache",
 ]
 
 _ALPHA_BACKENDS = ("auto", "numpy", "pallas")
@@ -178,6 +183,28 @@ class BackendConfig:
 
 config = BackendConfig.from_env()
 
+#: JAX's persistent compilation cache when JAX_COMPILATION_CACHE_DIR is
+#: unset: one fixed directory in the checkout (src/repro/core/ -> root),
+#: because the directory is part of every cache entry's key
+_CHECKOUT_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def configure_compile_cache() -> str:
+    """Place JAX's persistent compilation cache and return its directory.
+
+    Entry points (``chip_smoke.py``, ``benchmarks/run.py``, the examples)
+    call this once before their first compile; importing the library never
+    does.  When ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it,
+    and nothing is changed.  Otherwise the cache goes to ``.jax_cache`` at
+    the root of the checkout."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", str(_CHECKOUT_CACHE))
+    return str(_CHECKOUT_CACHE)
+
 
 def set_alpha_backend(name: str) -> None:
     """One-line switch: route merge_and_fix alphas through `name`."""
@@ -198,11 +225,9 @@ def use_alpha_backend(name: str):
 
 
 def _resolve_auto() -> str:
-    try:
-        import jax
-        return "pallas" if jax.default_backend() == "tpu" else "numpy"
-    except Exception:  # jax unavailable / misconfigured
-        return "numpy"
+    import jax
+
+    return "pallas" if jax.default_backend() == "tpu" else "numpy"
 
 
 def resolve_alpha_backend(force: str | None = None) -> str:
@@ -269,43 +294,25 @@ def resolve_plan_backend(force: str | None = None) -> str:
     return name
 
 
-_warned_fallback = False
-
-
 def compute_alphas(events: np.ndarray, edges, m: int,
                    force: str | None = None) -> np.ndarray:
     """Per-interval alphas (max per-port packet count) for merge_and_fix.
 
     `edges` is a timeline.EdgeIntervals; `events` the sorted unique interval
     boundaries.  Dispatches per :func:`resolve_alpha_backend` (the two
-    backends agree exactly — both count integer edge activations per port).
-    A kernel error falls back to the numpy oracle ONLY when pallas was
-    picked by "auto"; an explicitly requested pallas backend (force, env
-    var, or set_alpha_backend) propagates the error so parity tests and
-    benchmarks cannot silently pass on the oracle alone.
+    backends agree exactly — both count integer edge activations per port);
+    a kernel error propagates.
     """
     from .timeline import _alphas_vectorized  # oracle (import cycle: lazy)
 
-    requested = force or config.alpha_backend
-    backend = resolve_alpha_backend(force)
-    if backend == "pallas" and edges.size and events.size > 1:
-        try:
-            from repro.kernels.coflow_merge.ops import edge_interval_alphas
+    if resolve_alpha_backend(force) == "pallas" and edges.size \
+            and events.size > 1:
+        from repro.kernels.coflow_merge.ops import edge_interval_alphas
 
-            return np.asarray(
-                edge_interval_alphas(events, edges.t0, edges.t1,
-                                     edges.s, edges.r, m),
-                dtype=np.int64)
-        except Exception as exc:  # pragma: no cover - env-dependent
-            if requested == "pallas":
-                raise
-            global _warned_fallback
-            if not _warned_fallback:
-                _warned_fallback = True
-                warnings.warn(
-                    f"coflow_merge pallas backend failed ({exc!r}); "
-                    "auto-dispatch falling back to the numpy oracle",
-                    RuntimeWarning)
+        return np.asarray(
+            edge_interval_alphas(events, edges.t0, edges.t1,
+                                 edges.s, edges.r, m),
+            dtype=np.int64)
     return _alphas_vectorized(events, edges, m)
 
 
@@ -313,53 +320,29 @@ def compute_alphas(events: np.ndarray, edges, m: int,
 # jit planning pipeline dispatch (REPRO_PLAN_BACKEND; see core/pipeline.py)
 # --------------------------------------------------------------------------
 
-_warned_plan_fallback = False
-
-
-def _plan_fallback(exc: Exception) -> None:
-    """Auto falls back to the python plan path (warned once); an explicitly
-    requested jit backend propagates the error — mirroring the kernel
-    knobs, so equivalence tests cannot silently pass on the python path."""
-    global _warned_plan_fallback
-    if config.plan_backend == "jit":
-        raise exc
-    if not _warned_plan_fallback:
-        _warned_plan_fallback = True
-        warnings.warn(
-            f"jit planning pipeline failed ({exc!r}); auto-dispatch "
-            "falling back to the python plan path", RuntimeWarning)
-
-
 def prefetch_plan(demands: "Iterable[np.ndarray]") -> None:
     """Instance-level prefetch dispatched on the plan backend: under jit it
     warms the BNA *and* edge-interval caches through the compiled
-    width-bucketed sweep (pipeline.prefetch_demands); otherwise — or on an
-    auto-mode pipeline failure — it is exactly :func:`prefetch_bna`."""
+    width-bucketed sweep (pipeline.prefetch_demands); otherwise it is
+    exactly :func:`prefetch_bna`."""
     ds = list(demands)
     if resolve_plan_backend() == "jit":
-        try:
-            from . import pipeline
+        from . import pipeline
 
-            pipeline.prefetch_demands(ds)
-            return
-        except Exception as exc:  # pragma: no cover - env-dependent
-            _plan_fallback(exc)
+        pipeline.prefetch_demands(ds)
+        return
     prefetch_bna(ds)
 
 
 def plan_edges(demand: np.ndarray):
     """Relative (t0, t1, s, r) edge intervals of one coflow's BNA schedule
     under the jit plan backend; None routes the caller to the python path
-    (backend resolves python, or auto-mode pipeline failure)."""
+    (the plan backend resolves python)."""
     if resolve_plan_backend() != "jit":
         return None
-    try:
-        from . import pipeline
+    from . import pipeline
 
-        return pipeline.coflow_edges_rel(demand)
-    except Exception as exc:  # pragma: no cover - env-dependent
-        _plan_fallback(exc)
-        return None
+    return pipeline.coflow_edges_rel(demand)
 
 
 def plan_order_loads(instance):
@@ -367,13 +350,9 @@ def plan_order_loads(instance):
     integer sums); None routes the caller to the host computation."""
     if resolve_plan_backend() != "jit":
         return None
-    try:
-        from . import pipeline
+    from . import pipeline
 
-        return pipeline.instance_load_vectors(instance)
-    except Exception as exc:  # pragma: no cover - env-dependent
-        _plan_fallback(exc)
-        return None
+    return pipeline.instance_load_vectors(instance)
 
 
 def fused_merge_fix(events: np.ndarray, edges, m: int,
@@ -385,23 +364,16 @@ def fused_merge_fix(events: np.ndarray, edges, m: int,
     two-stage path.  Bit-identical: same kernel alphas, integer deltas."""
     if resolve_plan_backend() != "jit":
         return None
-    requested = force or config.alpha_backend
     if resolve_alpha_backend(force) != "pallas":
         return None
     if not (edges.size and events.size > 1):
         return None
-    try:
-        from repro.kernels.merge_fix.ops import merge_fix_step
+    from repro.kernels.merge_fix.ops import merge_fix_step
 
-        alphas, deltas = merge_fix_step(events, edges.t0, edges.t1,
-                                        edges.s, edges.r, m)
-        return (np.asarray(alphas, dtype=np.int64),
-                np.asarray(deltas, dtype=np.int64))
-    except Exception as exc:  # pragma: no cover - env-dependent
-        if requested == "pallas":
-            raise
-        _plan_fallback(exc)
-        return None
+    alphas, deltas = merge_fix_step(events, edges.t0, edges.t1,
+                                    edges.s, edges.r, m)
+    return (np.asarray(alphas, dtype=np.int64),
+            np.asarray(deltas, dtype=np.int64))
 
 
 # --------------------------------------------------------------------------
@@ -711,7 +683,8 @@ def cache_stats() -> dict:
              "order": order_cache.stats(),
              "group": group_cache.stats(),
              "loads": loads_cache.stats(),
-             "gkey": {**gkey_cache.stats(), "prefix": dict(_gkey_counts)}}
+             "gkey": {**gkey_cache.stats(), "prefix": dict(_gkey_counts)},
+             "launches": launch_counts()}
     if "repro.core.pipeline" in sys.modules:
         stats["plan"] = sys.modules["repro.core.pipeline"].pipeline_stats()
     return stats
@@ -735,6 +708,7 @@ def clear_caches() -> None:
         _bna_batch[k] = 0
     for k in _gkey_counts:
         _gkey_counts[k] = 0
+    reset_launch_counts()
     if "repro.core.pipeline" in sys.modules:
         # result caches only; compiled executables are data-independent
         sys.modules["repro.core.pipeline"].clear_pipeline_caches()

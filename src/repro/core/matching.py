@@ -34,21 +34,18 @@ replaces len(batch) scalar steps' worth of small-array numpy dispatch.
 The batched step dispatches through the ``REPRO_BNA_BACKEND`` knob
 (``core/backend.py``): ``numpy`` runs the in-place vectorized step below;
 ``pallas`` routes the same arithmetic through the ``kernels/bna_step``
-Pallas kernel (interpret mode on CPU, compiled on TPU); ``auto`` picks
-pallas iff a TPU is attached.  The two are bit-identical (integer
-arithmetic, same formulas); a kernel failure under ``auto`` falls back to
-numpy with a one-time warning, an explicitly requested pallas backend
-propagates the error.
+Pallas kernel (Mosaic-compiled on a TPU, interpret mode elsewhere);
+``auto`` picks pallas iff the backend is a TPU.  The two are bit-identical
+(integer arithmetic, same formulas); a kernel failure raises.  A bucket
+past the kernel's int32 range runs the numpy step, which is exact there.
 
 The jit planning pipeline (``core/pipeline.py``, ``REPRO_PLAN_BACKEND``)
 reuses this module's support-restrict/bucket/pack machinery and
-``_bna_core_batch`` as its python fallback; its compiled decomposition is a
-jnp mirror of :func:`bna_step_inplace` plus a vmapped repair, proven (and
-tested) to produce the same per-lane step sequences.
+``_bna_core_batch`` for buckets past its int32 range guard; its compiled
+decomposition is a jnp mirror of :func:`bna_step_inplace` plus a vmapped
+repair, proven (and tested) to produce the same per-lane step sequences.
 """
 from __future__ import annotations
-
-import warnings
 
 import numpy as np
 
@@ -243,38 +240,21 @@ def bna_step_inplace(
     return t, piece, D2, invalid
 
 
-_warned_bna_fallback = False
+def _resolve_step(force: str | None, B: int, w: int, D_max: int):
+    """The pallas batched step for a (B, w, w) bucket whose effective sizes
+    are at most `D_max`, per the REPRO_BNA_BACKEND dispatch (see
+    backend.py); None runs the numpy step.  A bucket past the kernel's
+    int32 range guards stays on the numpy step, exactly as
+    ``coflow_merge.ops.kernel_alphas`` keeps its alphas on the reference:
+    that is an exactness guard, not a device fallback."""
+    from .backend import resolve_bna_backend
 
+    if resolve_bna_backend(force) != "pallas":
+        return None
+    # repro: allow(backend-dispatch): this IS the REPRO_BNA_BACKEND resolved dispatch site
+    from repro.kernels.bna_step.ops import bna_step_batch, int32_exact
 
-def _resolve_step(force: str | None):
-    """(step_fn, backend_name): the batched-step implementation for this
-    call per the REPRO_BNA_BACKEND dispatch (see backend.py)."""
-    from .backend import config, resolve_bna_backend
-
-    requested = force or config.bna_backend
-    name = resolve_bna_backend(force)
-    if name != "pallas":
-        return None, "numpy"
-
-    def step_pallas(d, row, col, D, match):
-        global _warned_bna_fallback
-        try:
-            # repro: allow(backend-dispatch): this IS the REPRO_BNA_BACKEND resolved dispatch site
-            from repro.kernels.bna_step.ops import bna_step_batch
-
-            return bna_step_batch(d, row, col, D, match)
-        except Exception as exc:  # pragma: no cover - env-dependent
-            if requested == "pallas":
-                raise
-            if not _warned_bna_fallback:
-                _warned_bna_fallback = True
-                warnings.warn(
-                    f"bna_step pallas backend failed ({exc!r}); "
-                    "auto-dispatch falling back to the numpy step",
-                    RuntimeWarning)
-            return None
-
-    return step_pallas, "pallas"
+    return bna_step_batch if int32_exact(B, w, D_max) else None
 
 
 def _bna_core_batch(
@@ -303,7 +283,7 @@ def _bna_core_batch(
     ids = np.arange(B, dtype=np.int64)
     # scalar guard: nnz + 2m + 4 iterations, slack 4m — take the bucket max
     guard = int((d > 0).sum(axis=(1, 2)).max(initial=0)) + 6 * w + 8
-    step_pallas, _backend = _resolve_step(force)
+    step_pallas = _resolve_step(force, B, w, int(D.max(initial=0)))
     steps: list[tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]] = []
     it = 0
     while True:
@@ -315,11 +295,8 @@ def _bna_core_batch(
             raise AssertionError("batched BNA failed to terminate (bug)")
 
         if step_pallas is not None:
-            res = step_pallas(d, row, col, D, match_sr)
-            if res is None:        # auto-dispatch fallback, rest of bucket
-                step_pallas = None
-        if step_pallas is not None:
-            t, piece, d, row, col, D, invalid = res
+            t, piece, d, row, col, D, invalid = step_pallas(
+                d, row, col, D, match_sr)
         else:
             t, piece, D, invalid = bna_step_inplace(d, row, col, D, match_sr)
         assert bool((t[alive] > 0).all()), "zero-length BNA step (bug)"

@@ -55,6 +55,8 @@ import warnings
 
 import numpy as np
 
+from repro.kernels import count_launch
+
 from . import backend as _backend
 from .bna import _NO_MATCH, expand_pieces, support_restrict
 from .matching import _bna_core_batch, bucket_width
@@ -70,6 +72,10 @@ __all__ = [
 ]
 
 _INT32_MAX = int(np.iinfo(np.int32).max)
+
+#: per-program cap on the (lanes, T_cap, w) int32 piece stack a compiled
+#: decomposition returns (a v5e chip holds 16 GB of HBM)
+_PIECES_BYTES = 1 << 30
 
 #: demand key -> (t0, t1, s, r) int64 *relative* edge intervals (start = 0);
 #: the jit-path replacement for re-running the Python RLE per plan.
@@ -326,17 +332,40 @@ class _BucketOverflow(Exception):
 
 def _decompose_bucket_jit(subs: list[np.ndarray], w: int):
     """Decompose one width bucket through the compiled path; returns per
-    matrix ``(pieces_restricted, (t0, t1, s, r) restricted rel-edges)``."""
-    B = len(subs)
-    B_pad = _pow2(B)
+    matrix ``(pieces_restricted, (t0, t1, s, r) restricted rel-edges)``.
+
+    The bucket runs in chunks of at most ``_chunk_lanes(w, T_cap)`` lanes,
+    so no compiled program's ``(lanes, T_cap, w)`` piece stack outgrows
+    ``_PIECES_BYTES``: a chip-size bucket (m = 150 lands half the trace's
+    coflows at w = 256, with T_cap up to 32768, 32 MiB per lane) would
+    otherwise need more HBM than one chip has.  Lanes are independent, so chunking cannot
+    change any lane's steps."""
     nnz = max(int((s > 0).sum()) for s in subs)
     T_cap = _pow2(nnz + 6 * w + 8)
-    d = np.zeros((B_pad, w, w), np.int32)
-    ks = np.zeros(B_pad, np.int32)
-    for i, s in enumerate(subs):
+    for s in subs:
         if max(int(s.sum(axis=1).max()), int(s.sum(axis=0).max())) \
                 >= _INT32_MAX:
             raise _BucketOverflow
+    lanes = _chunk_lanes(w, T_cap)
+    out = []
+    for lo in range(0, len(subs), lanes):
+        out.extend(_decompose_chunk(subs[lo:lo + lanes], w, T_cap))
+    return out
+
+
+def _chunk_lanes(w: int, T_cap: int) -> int:
+    """Largest power-of-two lane count whose int32 piece stack fits
+    ``_PIECES_BYTES`` (at least one lane)."""
+    per_lane = T_cap * w * 4
+    return 1 << max((_PIECES_BYTES // per_lane).bit_length() - 1, 0)
+
+
+def _decompose_chunk(subs: list[np.ndarray], w: int, T_cap: int):
+    B = len(subs)
+    B_pad = _pow2(B)
+    d = np.zeros((B_pad, w, w), np.int32)
+    ks = np.zeros(B_pad, np.int32)
+    for i, s in enumerate(subs):
         k = s.shape[0]
         d[i, :k, :k] = s
         ks[i] = k
@@ -347,6 +376,7 @@ def _decompose_bucket_jit(subs: list[np.ndarray], w: int):
              jax.ShapeDtypeStruct((B_pad,), np.int32))
     fn = _get_compiled(("bna", B_pad, w, T_cap),
                        lambda: _build_decompose(w, T_cap), avals)
+    count_launch("decompose")
     ts, pieces, D_end = (np.asarray(x) for x in fn(d, ks))
     if D_end.any():
         raise AssertionError("jitted BNA failed to terminate (bug)")
@@ -372,7 +402,9 @@ def _decompose_bucket_py(subs: list[np.ndarray], w: int):
             "on the numpy path (results are identical)", RuntimeWarning)
     _counters["bucket_fallbacks"] += 1
     out = []
-    for plist in _bna_core_batch(subs, w):
+    # the bucket is past the int32 range, so the pallas step's own guard
+    # would refuse it as well
+    for plist in _bna_core_batch(subs, w, force="numpy"):
         ei = bna_pieces_to_edge_intervals(plist, 0)
         out.append((plist, (ei.t0, ei.t1, ei.s, ei.r)))
     return out
@@ -524,4 +556,5 @@ def instance_load_vectors(instance) -> np.ndarray | None:
              jax.ShapeDtypeStruct((C_pad,), np.int32))
     fn = _get_compiled(("loads", C_pad, m, n_pad),
                        lambda: _build_loads(m, n_pad), avals)
+    count_launch("loads")
     return np.asarray(fn(dstack, seg))[:n].astype(np.float64)

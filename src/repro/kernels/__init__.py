@@ -15,24 +15,36 @@ Kernels:
                     transmissions, matched-edge invalidation) over a
                     (B, w, w) demand stack; bit-identical to its numpy ref
 
-TPU is the *target*; on this CPU-only container every kernel runs in
-interpret mode (the kernel body executes in Python), which is how the test
-suite validates them against the refs.
+On a TPU backend every kernel is compiled by Mosaic; on any other backend
+`default_interpret()` is True and the kernels run in Pallas interpret mode,
+which is how the CPU test suite validates them against the refs.  Whether
+the scheduler kernels compile for a v5e is checked without a chip by
+``tests/test_chip_compile.py``; ``chip_smoke.py`` runs them on one.
+
+Every launch of a scheduler device program is counted here, at the one
+place that launches it (``count_launch``): the Pallas kernels after their
+int32 range guards, and the jitted planning pipeline's ``decompose`` and
+``loads`` programs.  ``repro.core.cache_stats()["launches"]`` reports the
+counts, so a run shows which device paths did its work.
 """
+
+_launches = {"coflow_merge": 0, "bna_step": 0, "decompose": 0, "loads": 0}
+
+
+def count_launch(name: str) -> None:
+    _launches[name] += 1
+
+
+def launch_counts() -> dict:
+    return dict(_launches)
+
+
+def reset_launch_counts() -> None:
+    for k in _launches:
+        _launches[k] = 0
 
 
 def default_interpret() -> bool:
     import jax
 
     return jax.default_backend() != "tpu"
-
-
-def tpu_compiler_params(**kwargs):
-    """Version-portable pltpu compiler params: the class is named
-    `CompilerParams` on current jax and `TPUCompilerParams` on the 0.4.x
-    series this container ships."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)

@@ -11,14 +11,19 @@ is emitted.  Everything is elementwise/reduction int32 arithmetic — the
 kernel is BIT-IDENTICAL to the numpy oracle (`ref.bna_step_ref`), which is
 what lets `REPRO_BNA_BACKEND=pallas` keep plans byte-for-byte equal.
 
-TPU mapping: grid over B-blocks ("parallel" — matrices are independent),
-each step loading a (block_b, w, w) demand tile plus its (block_b, w) state
-rows into VMEM.  The gather is realized as a one-hot broadcast-compare
-(match index vs a receiver iota) followed by a masked reduction — the
-standard TPU trick for small-axis gathers, keeping the whole body on the
-VPU.  Arithmetic intensity is ~3 ops/byte over the w*w tile: memory-bound,
-like coflow_merge; the roofline section of `benchmarks.roofline_report`
-reports the memory term at K -> 1e5.
+TPU mapping: grid over the B matrices ("parallel" — matrices are
+independent), one (w, w) demand tile per step.  Every value stays 2-D:
+sender-indexed state (row loads, the matching, and the per-sender outputs)
+travels as (w, 1) columns, receiver-indexed state (col loads) as (1, w)
+rows, and the per-matrix scalars (D and the step length) as (1, 1)
+blocks reduced to true scalars in the body.  The gather is a one-hot
+compare of the match column against a receiver iota followed by a
+keepdims lane reduction, so no value is ever reshaped from 2-D to 3-D
+(Mosaic cannot lay such a cast out).  The jitted wrapper below moves the
+(B, w) state between the caller's layout and the kernel's.  Arithmetic
+intensity is ~3 ops/byte over the w*w tile: memory-bound, like
+coflow_merge; the roofline section of `benchmarks.roofline_report` reports
+the memory term at K -> 1e5.
 """
 from __future__ import annotations
 
@@ -27,8 +32,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .. import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 _NO_MATCH = -1
 _BIG = jnp.iinfo(jnp.int32).max
@@ -37,89 +41,82 @@ _BIG = jnp.iinfo(jnp.int32).max
 def _bna_step_kernel(d_ref, row_ref, col_ref, D_ref, match_ref,
                      t_ref, piece_ref, dn_ref, rown_ref, coln_ref,
                      Dn_ref, inv_ref):
-    d = d_ref[...]                     # (Bb, w, w) int32
-    row = row_ref[...]                 # (Bb, w)
-    col = col_ref[...]
-    Dv = D_ref[...]                    # (Bb, 1)
-    match = match_ref[...]             # (Bb, w)
+    d = d_ref[...]                     # (w, w) int32
+    row = row_ref[...]                 # (w, 1) per sender
+    col = col_ref[...]                 # (1, w) per receiver
+    Dv = jnp.min(D_ref[...])           # scalar
+    match = match_ref[...]             # (w, 1) per sender
 
-    r_ids = jax.lax.broadcasted_iota(jnp.int32, d.shape, dimension=2)
-    onehot = (r_ids == match[:, :, None]) & (match[:, :, None] != _NO_MATCH)
-    dm = jnp.sum(jnp.where(onehot, d, 0), axis=2)          # (Bb, w)
-    real = (match != _NO_MATCH) & (dm > 0)
+    r_ids = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
+    matched = match != _NO_MATCH
+    onehot = (r_ids == match) & matched                      # (w, w)
+    dm = jnp.sum(jnp.where(onehot, d, 0), axis=1, keepdims=True)
+    real = matched & (dm > 0)                                # (w, 1)
+    served = onehot & real                                   # (w, w)
+    recv = jnp.max(served.astype(jnp.int32), axis=0, keepdims=True) > 0
 
-    t = jnp.min(jnp.where(real, dm, _BIG), axis=1, keepdims=True)
-    t = jnp.minimum(t, jnp.min(jnp.where(~real, Dv - row, _BIG),
-                               axis=1, keepdims=True))
-    recv = jnp.any(onehot & real[:, :, None], axis=1)      # (Bb, w)
-    t = jnp.minimum(t, jnp.min(jnp.where(~recv, Dv - col, _BIG),
-                               axis=1, keepdims=True))
+    # step length as a scalar: full reductions, so it splats to any shape
+    t = jnp.minimum(
+        jnp.minimum(jnp.min(jnp.where(real, dm, _BIG)),
+                    jnp.min(jnp.where(real, _BIG, Dv - row))),
+        jnp.min(jnp.where(recv, _BIG, Dv - col)))
 
-    served = onehot & real[:, :, None]
-    dn = d - jnp.where(served, t[:, :, None], 0)
+    dn = d - jnp.where(served, t, 0)
     rown = row - jnp.where(real, t, 0)
     coln = col - jnp.where(recv, t, 0)
     Dn = Dv - t
 
     dmn = dm - jnp.where(real, t, 0)
-    colm = jnp.sum(jnp.where(onehot, coln[:, None, :], 0), axis=2)
-    invalid = (match != _NO_MATCH) & (dmn == 0) \
-        & ((rown >= Dn) | (colm >= Dn)) & (Dn > 0)
+    colm = jnp.sum(jnp.where(onehot, coln, 0), axis=1, keepdims=True)
+    invalid = matched & (dmn == 0) & ((rown >= Dn) | (colm >= Dn)) & (Dn > 0)
 
-    t_ref[...] = t
+    t_ref[...] = jnp.full(t_ref.shape, t, jnp.int32)
     piece_ref[...] = jnp.where(real, match, _NO_MATCH)
     dn_ref[...] = dn
     rown_ref[...] = rown
     coln_ref[...] = coln
-    Dn_ref[...] = Dn
+    Dn_ref[...] = jnp.full(Dn_ref.shape, Dn, jnp.int32)
     inv_ref[...] = invalid.astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def bna_step_padded(
-    d: jax.Array,       # (B_pad, w_pad, w_pad) int32, B_pad % block_b == 0
-    row: jax.Array,     # (B_pad, w_pad) int32
+    d: jax.Array,       # (B, w_pad, w_pad) int32
+    row: jax.Array,     # (B, w_pad) int32
     col: jax.Array,
-    D: jax.Array,       # (B_pad, 1) int32
-    match: jax.Array,   # (B_pad, w_pad) int32
+    D: jax.Array,       # (B, 1) int32
+    match: jax.Array,   # (B, w_pad) int32
     *,
-    block_b: int,
     interpret: bool,
 ):
+    """One step over the stack; outputs in the caller's layout:
+    (t (B, 1), piece, d', row', col', D' (B, 1), invalid (B, w))."""
     B, w, _ = d.shape
-    assert B % block_b == 0
-    grid = (B // block_b,)
     i32 = jnp.int32
-    return pl.pallas_call(
+
+    def spec(*shape):
+        return pl.BlockSpec((None,) + shape,
+                            lambda b: (b,) + (0,) * len(shape))
+
+    column, lanes, scalar = spec(w, 1), spec(1, w), spec(1, 1)
+    t, piece, dn, rown, coln, Dn, inv = pl.pallas_call(
         _bna_step_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, w, w), lambda ib: (ib, 0, 0)),
-            pl.BlockSpec((block_b, w), lambda ib: (ib, 0)),
-            pl.BlockSpec((block_b, w), lambda ib: (ib, 0)),
-            pl.BlockSpec((block_b, 1), lambda ib: (ib, 0)),
-            pl.BlockSpec((block_b, w), lambda ib: (ib, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_b, 1), lambda ib: (ib, 0)),
-            pl.BlockSpec((block_b, w), lambda ib: (ib, 0)),
-            pl.BlockSpec((block_b, w, w), lambda ib: (ib, 0, 0)),
-            pl.BlockSpec((block_b, w), lambda ib: (ib, 0)),
-            pl.BlockSpec((block_b, w), lambda ib: (ib, 0)),
-            pl.BlockSpec((block_b, 1), lambda ib: (ib, 0)),
-            pl.BlockSpec((block_b, w), lambda ib: (ib, 0)),
-        ],
+        grid=(B,),
+        in_specs=[spec(w, w), column, lanes, scalar, column],
+        out_specs=[scalar, column, spec(w, w), column, lanes, scalar, column],
         out_shape=[
-            jax.ShapeDtypeStruct((B, 1), i32),       # t
-            jax.ShapeDtypeStruct((B, w), i32),       # piece
+            jax.ShapeDtypeStruct((B, 1, 1), i32),    # t
+            jax.ShapeDtypeStruct((B, w, 1), i32),    # piece
             jax.ShapeDtypeStruct((B, w, w), i32),    # d'
-            jax.ShapeDtypeStruct((B, w), i32),       # row'
-            jax.ShapeDtypeStruct((B, w), i32),       # col'
-            jax.ShapeDtypeStruct((B, 1), i32),       # D'
-            jax.ShapeDtypeStruct((B, w), i32),       # invalid
+            jax.ShapeDtypeStruct((B, w, 1), i32),    # row'
+            jax.ShapeDtypeStruct((B, 1, w), i32),    # col'
+            jax.ShapeDtypeStruct((B, 1, 1), i32),    # D'
+            jax.ShapeDtypeStruct((B, w, 1), i32),    # invalid
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
-    )(d, row, col, D, match)
+    )(d, row[:, :, None], col[:, None, :], D[:, :, None], match[:, :, None])
+    return (t[:, :, 0], piece[:, :, 0], dn, rown[:, :, 0], coln[:, 0, :],
+            Dn[:, :, 0], inv[:, :, 0])

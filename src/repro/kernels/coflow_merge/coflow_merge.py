@@ -9,7 +9,10 @@ intervals (hundreds of thousands for the full Facebook-trace workload).
 TPU mapping: grid over K-blocks, sequential ("arbitrary"), carrying the
 running port counts (1, 2m) in VMEM scratch. Each step loads a
 (block_k, 2m) delta tile into VMEM (2m padded to a 128 multiple by ops.py),
-does a cumsum down the time axis plus the carry, and writes the per-row max.
+does a prefix sum down the time axis plus the carry, and writes the per-row
+max.  Mosaic has no cumsum, so the prefix sum is a log-step (Hillis-Steele)
+scan: log2(block_k) rounds of a sublane roll masked by a row iota, all in
+exact int32 (every partial sum is a contiguous-range count, bounded by E).
 Memory-bound by design: one pass over the delta array, arithmetic intensity
 ~2 ops/byte — the roofline benchmark for this kernel reports the memory
 term, matching the analysis in EXPERIMENTS.md.
@@ -23,7 +26,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import tpu_compiler_params
+
+
+def _prefix_sum_rows(x: jax.Array) -> jax.Array:
+    """Inclusive prefix sum down axis 0 of a (rows, lanes) int32 tile."""
+    rows = x.shape[0]
+    row_ids = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    shift = 1
+    while shift < rows:
+        x = x + jnp.where(row_ids >= shift, pltpu.roll(x, shift, 0), 0)
+        shift *= 2
+    return x
 
 
 def _merge_kernel(delta_ref, alpha_ref, carry_ref):
@@ -34,7 +47,7 @@ def _merge_kernel(delta_ref, alpha_ref, carry_ref):
         carry_ref[...] = jnp.zeros_like(carry_ref)
 
     delta = delta_ref[...].astype(jnp.int32)          # (Bk, 2m)
-    counts = carry_ref[...] + jnp.cumsum(delta, axis=0)
+    counts = carry_ref[...] + _prefix_sum_rows(delta)
     alpha_ref[...] = counts.max(axis=1, keepdims=True)
     carry_ref[...] = counts[-1:, :]
 
@@ -56,7 +69,7 @@ def coflow_merge_padded(
         out_specs=pl.BlockSpec((block_k, 1), lambda ib: (ib, 0)),
         out_shape=jax.ShapeDtypeStruct((K, 1), jnp.int32),
         scratch_shapes=[pltpu.VMEM((1, ports), jnp.int32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import default_interpret
+from .. import count_launch, default_interpret
 from .coflow_merge import coflow_merge_padded
 from .ref import alphas_ref, build_delta
 
@@ -40,16 +40,27 @@ def interval_alphas(
                         jnp.asarray(r), K, m)
     if not use_kernel:
         return np.asarray(alphas_ref(delta), dtype=np.int64)
+    return np.asarray(kernel_alphas(delta, block_k=block_k,
+                                    interpret=interpret), dtype=np.int64)
+
+
+def kernel_alphas(delta: jax.Array, *, block_k: int,
+                  interpret: bool) -> jax.Array:
+    """(K,) int32 alphas of a (K, ports) delta array through the kernel:
+    K is padded to a power-of-two block (at most `block_k`, at least 8)
+    and ports to the 128-lane multiple.  Shared by `interval_alphas` and
+    the fused merge_fix step."""
+    K, ports = delta.shape
     bk = min(block_k, max(8, 1 << (K - 1).bit_length()))
     k_pad = (-K) % bk
-    p_pad = (-delta.shape[1]) % 128
+    p_pad = (-ports) % 128
     # Pallas indexes the padded delta with int32 arithmetic; past that the
     # jnp reference (64-bit indexing) is the only correct path.
-    if (K + k_pad) * (delta.shape[1] + p_pad) >= _I32_MAX:
-        return np.asarray(alphas_ref(delta), dtype=np.int64)
+    if (K + k_pad) * (ports + p_pad) >= _I32_MAX:
+        return alphas_ref(delta)
+    count_launch("coflow_merge")
     dpad = jnp.pad(delta, ((0, k_pad), (0, p_pad)))
-    out = coflow_merge_padded(dpad, block_k=bk, interpret=interpret)
-    return np.asarray(out[:K, 0], dtype=np.int64)
+    return coflow_merge_padded(dpad, block_k=bk, interpret=interpret)[:K, 0]
 
 
 def edge_interval_alphas(

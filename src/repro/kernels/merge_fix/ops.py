@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import default_interpret
-from ..coflow_merge.coflow_merge import coflow_merge_padded
+from ..coflow_merge.ops import kernel_alphas
 from ..coflow_merge.ref import alphas_ref, build_delta
 
 _INT32_MAX = np.int64(2**31 - 1)
@@ -55,16 +55,7 @@ def merge_fix_step(
     delta = build_delta(jnp.asarray(si), jnp.asarray(ei), jnp.asarray(s),
                         jnp.asarray(r), K, m)
     if use_kernel:
-        bk = min(block_k, max(8, 1 << (K - 1).bit_length()))
-        k_pad = (-K) % bk
-        p_pad = (-delta.shape[1]) % 128
-        if (K + k_pad) * (delta.shape[1] + p_pad) >= int(_INT32_MAX):
-            # padded index space would wrap int32 inside the kernel
-            al = alphas_ref(delta)
-        else:
-            dpad = jnp.pad(delta, ((0, k_pad), (0, p_pad)))
-            al = coflow_merge_padded(
-                dpad, block_k=bk, interpret=interpret)[:K, 0]
+        al = kernel_alphas(delta, block_k=block_k, interpret=interpret)
     else:
         al = alphas_ref(delta)
     lens = np.asarray(events[1:] - events[:-1], dtype=np.int64)

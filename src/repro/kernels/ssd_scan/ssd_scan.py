@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import tpu_compiler_params
 
 
 def _ssd_kernel(x_ref, loga_ref, b_ref, c_ref, y_ref, h_ref, *, chunk: int):
@@ -93,7 +92,7 @@ def ssd_scan_padded(
         out_specs=pl.BlockSpec((1, chunk, 1, P), lambda bb, h, ic: (bb, ic, h, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -165,6 +165,29 @@ def test_decompose_sparse_support_padding():
     _assert_decomp_matches(demands)
 
 
+def test_decompose_bucket_in_lane_chunks(monkeypatch):
+    """A bucket wider than one chunk runs as several compiled programs
+    (here two lanes each, so chunks of 2, 2 and 1) and still decomposes
+    every demand exactly like the scalar bna."""
+    rng = np.random.default_rng(13)
+    demands = [_rand_demand(rng, 6, density=0.6) for _ in range(5)]
+    T_cap = pipeline._pow2(max(int((d > 0).sum()) for d in demands)
+                           + 6 * 8 + 8)
+    monkeypatch.setattr(pipeline, "_PIECES_BYTES", 2 * T_cap * 8 * 4)
+    assert pipeline._chunk_lanes(8, T_cap) == 2
+    before = cache_stats()["launches"]["decompose"]
+    _assert_decomp_matches(demands)
+    assert cache_stats()["launches"]["decompose"] == before + 3
+
+
+def test_chunk_lanes_bound_piece_stack():
+    for w, T_cap in ((8, 64), (128, 4096), (256, 32768), (256, 1 << 22)):
+        lanes = pipeline._chunk_lanes(w, T_cap)
+        assert lanes >= 1 and lanes & (lanes - 1) == 0
+        assert lanes == 1 or lanes * T_cap * w * 4 <= pipeline._PIECES_BYTES
+        assert 2 * lanes * T_cap * w * 4 > pipeline._PIECES_BYTES
+
+
 # --------------------------------------------------------------------------
 # structural instance-level edge cases
 # --------------------------------------------------------------------------
@@ -283,3 +306,79 @@ def test_prefetch_python_backend_untouched():
         prefetch_plan(demands)  # routes to prefetch_bna, not the pipeline
         assert cache_stats()["plan"]["edges"]["size"] == 0
         assert backend.plan_edges(demands[0]) is None
+
+
+class _DeviceDown(RuntimeError):
+    pass
+
+
+def _raise_device_down(*_a, **_k):
+    raise _DeviceDown("device path failed")
+
+
+@pytest.mark.parametrize("site,target,plan_backend", [
+    ("pipeline", "repro.core.pipeline.prefetch_demands", "auto"),
+    ("merge_fix", "repro.kernels.merge_fix.ops.merge_fix_step", "auto"),
+    ("coflow_merge", "repro.kernels.coflow_merge.ops.edge_interval_alphas",
+     "python"),
+    ("bna_step", "repro.kernels.bna_step.ops.bna_step_batch", "python"),
+])
+def test_auto_device_failure_raises(monkeypatch, site, target, plan_backend):
+    """With ``auto`` resolving to the device backends (as on a TPU), a
+    failing kernel or pipeline stage raises out of ``plan``: no backend
+    quietly hands the work to the host and warns."""
+    import importlib
+    import warnings
+
+    module, name = target.rsplit(".", 1)
+    monkeypatch.setattr(importlib.import_module(module), name,
+                        _raise_device_down)
+    monkeypatch.setattr(backend, "_resolve_auto", lambda: "pallas")
+    built = _tiny("incast")
+    with use_plan_backend(plan_backend), \
+            backend.use_alpha_backend("auto"), \
+            backend.use_bna_backend("auto"), warnings.catch_warnings():
+        warnings.simplefilter("error")
+        clear_caches()
+        with pytest.raises(_DeviceDown):
+            plan(built.instance, "gdm", seed=0)
+    clear_caches()
+
+
+@pytest.mark.parametrize("path", ("plan", "bna_many"))
+def test_int32_guard_stays_exact_under_auto(monkeypatch, path):
+    """A demand whose row sum passes 2^31 - 1 is past every int32 device
+    program.  With ``auto`` resolving to the device backends (as on a
+    TPU), the range guards keep its bucket on the numpy step: the plan
+    and the pieces stay exact, the pallas step is never launched, and the
+    jit pipeline counts one bucket fallback."""
+    import warnings
+
+    from repro.core.matching import bna_many
+
+    big = np.array([[2**30, 2**30, 0],
+                    [0, 3, 2**30],
+                    [5, 0, 7]], np.int64)
+    assert big.sum(axis=1).max() >= 2**31 - 1
+    inst = Instance(3, [Job(0, [Coflow(0, 0, big)], [], weight=1.0,
+                            release=0)])
+    with use_plan_backend("python"), backend.use_alpha_backend("numpy"), \
+            backend.use_bna_backend("numpy"):
+        clear_caches()
+        ref = _fingerprint(plan(inst, "gdm", seed=0))
+    monkeypatch.setattr(backend, "_resolve_auto", lambda: "pallas")
+    with use_plan_backend("auto"), backend.use_alpha_backend("auto"), \
+            backend.use_bna_backend("auto"), warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)
+        clear_caches()
+        if path == "plan":
+            assert _fingerprint(plan(inst, "gdm", seed=0)) == ref
+            assert cache_stats()["plan"]["compile"]["bucket_fallbacks"] == 1
+        else:
+            got = bna_many([big])[0]
+            want = bna(big)
+            assert len(got) == len(want)
+            assert all(ta == tb and np.array_equal(ma, mb)
+                       for (ta, ma), (tb, mb) in zip(got, want))
+        assert cache_stats()["launches"]["bna_step"] == 0
+    clear_caches()
