@@ -106,6 +106,8 @@ import numpy as np
 
 from repro.kernels import launch_counts, reset_launch_counts
 
+from .spans import span
+
 __all__ = [
     "BackendConfig",
     "config",
@@ -506,7 +508,9 @@ def bna_pieces_many(demands: list, keys: list | None = None) -> list:
         else:
             slot.append(i)
     if miss_demands:
-        for key, pieces in zip(miss_keys, bna_many(miss_demands)):
+        with span("plan.decompose", coflows=len(miss_demands)):
+            decomposed = bna_many(miss_demands)
+        for key, pieces in zip(miss_keys, decomposed):
             bna_cache.store(key, pieces)
             for i in by_key[key]:
                 out[i] = pieces
@@ -587,21 +591,23 @@ def group_block(kind: str, jobs, m: int, *, beta: float = 2.0,
             f"group_block caches spread-mode layouts only (got "
             f"delays={delays!r}): randomized modes consume rng draws")
     group_cache.maxsize = config.group_cache_size
-    key = (kind, int(m), float(beta), bool(decompose), use_kernel,
-           bool(nested), bool(require_tree), delays) + _group_sig(jobs)
-    found, part = group_cache.lookup(key)
-    if not found:
-        if kind == "gdm_rt":
-            part = dma_rt(list(jobs), m, beta=beta, rng=None, origin=0,
-                          decompose=decompose, use_kernel=use_kernel,
-                          nested=nested, require_tree=require_tree,
-                          delays=delays)
-        else:
-            part = dma(list(jobs), m, beta=beta, rng=None, origin=0,
-                       decompose=decompose, use_kernel=use_kernel,
-                       delays=delays)
-        group_cache.store(key, part)
-    return part
+    with span("plan.group_block") as sp:
+        key = (kind, int(m), float(beta), bool(decompose), use_kernel,
+               bool(nested), bool(require_tree), delays) + _group_sig(jobs)
+        found, part = group_cache.lookup(key)
+        sp.set(hit=found)
+        if not found:
+            if kind == "gdm_rt":
+                part = dma_rt(list(jobs), m, beta=beta, rng=None, origin=0,
+                              decompose=decompose, use_kernel=use_kernel,
+                              nested=nested, require_tree=require_tree,
+                              delays=delays)
+            else:
+                part = dma(list(jobs), m, beta=beta, rng=None, origin=0,
+                           decompose=decompose, use_kernel=use_kernel,
+                           delays=delays)
+            group_cache.store(key, part)
+        return part
 
 
 # --------------------------------------------------------------------------

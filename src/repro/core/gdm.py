@@ -44,6 +44,7 @@ import numpy as np
 
 from .ordering import cached_job_order
 from .result import CompositeSchedule
+from .spans import span
 from .types import Instance
 
 __all__ = ["gdm", "group_jobs", "GammaEpoch", "geometric_bucket"]
@@ -147,19 +148,20 @@ def group_jobs(instance: Instance, order: list[int],
     dropped (they contribute nothing to the schedule)."""
     from . import backend
 
-    by_id = {j.jid: j for j in instance.jobs}
-    if gamma is None:
-        gamma = instance.gamma()
-    g = Fraction(gamma)
-    if g <= 0:
-        raise ValueError(f"gamma must be positive, got {gamma!r}")
-    D = backend.grouping_prefix(instance, order)
-    groups: dict[int, list[int]] = {}
-    for i, jid in enumerate(order):
-        job = by_id[jid]
-        key = job.T + job.release + int(D[i])
-        groups.setdefault(geometric_bucket(key, g), []).append(jid)
-    return [groups[b] for b in sorted(groups)]
+    with span("plan.group"):
+        by_id = {j.jid: j for j in instance.jobs}
+        if gamma is None:
+            gamma = instance.gamma()
+        g = Fraction(gamma)
+        if g <= 0:
+            raise ValueError(f"gamma must be positive, got {gamma!r}")
+        D = backend.grouping_prefix(instance, order)
+        groups: dict[int, list[int]] = {}
+        for i, jid in enumerate(order):
+            job = by_id[jid]
+            key = job.T + job.release + int(D[i])
+            groups.setdefault(geometric_bucket(key, g), []).append(jid)
+        return [groups[b] for b in sorted(groups)]
 
 
 def gdm(

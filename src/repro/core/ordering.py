@@ -16,6 +16,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from .spans import span
 from .types import Instance, Job
 
 __all__ = ["job_order", "cached_job_order", "OrderResult",
@@ -124,10 +125,13 @@ def cached_job_order(instance: Instance) -> OrderResult:
     from . import backend
 
     backend.order_cache.maxsize = backend.config.order_cache_size
-    key = instance_signature(instance)
-    found, res = backend.order_cache.lookup(key)
-    if not found:
-        res = job_order(instance, loads=backend.plan_order_loads(instance))
-        backend.order_cache.store(key, res)
-    return OrderResult(list(res.order), dict(res.eta), list(res.lambdas),
-                       dict(res.residual))
+    with span("plan.order") as sp:
+        key = instance_signature(instance)
+        found, res = backend.order_cache.lookup(key)
+        sp.set(hit=found)
+        if not found:
+            res = job_order(instance,
+                            loads=backend.plan_order_loads(instance))
+            backend.order_cache.store(key, res)
+        return OrderResult(list(res.order), dict(res.eta),
+                           list(res.lambdas), dict(res.residual))

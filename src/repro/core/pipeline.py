@@ -60,6 +60,7 @@ from repro.kernels import count_launch
 from . import backend as _backend
 from .bna import _NO_MATCH, expand_pieces, support_restrict
 from .matching import _bna_core_batch, bucket_width
+from .spans import span
 
 __all__ = [
     "prefetch_demands",
@@ -414,37 +415,39 @@ def _plan_decompositions(demands: list[np.ndarray]):
     """(pieces, rel_edges) per demand: pieces are full-m (duration, match)
     lists bit-identical to ``bna.bna``; rel_edges are (t0, t1, s, r) int64
     edge intervals of the coflow's isolated schedule anchored at 0."""
-    _counters["batches"] += 1
-    out_p: list = [None] * len(demands)
-    out_e: list = [None] * len(demands)
-    buckets: dict[int, list] = {}
-    for i, dem in enumerate(demands):
-        d_full = np.asarray(dem, dtype=np.int64)
-        sub, rows_p, cols_p = support_restrict(d_full)
-        if sub is None:
-            z = np.zeros(0, np.int64)
-            out_p[i] = []
-            out_e[i] = (z, z.copy(), z.copy(), z.copy())
-            continue
-        w = bucket_width(sub.shape[0])
-        buckets.setdefault(w, []).append(
-            (i, sub, rows_p, cols_p, d_full.shape[0]))
-    for w in sorted(buckets):
-        items = buckets[w]
-        subs = [it[1] for it in items]
-        try:
-            res = _decompose_bucket_jit(subs, w)
-        except _BucketOverflow:
-            res = _decompose_bucket_py(subs, w)
-        for (i, _sub, rows_p, cols_p, m_full), (plist, rel) in zip(items, res):
-            if rows_p is None:
-                out_p[i] = plist
-                out_e[i] = rel
-            else:
-                out_p[i] = expand_pieces(plist, rows_p, cols_p, m_full)
-                t0, t1, ss, rr = rel
-                out_e[i] = (t0, t1, rows_p[ss], cols_p[rr])
-    return out_p, out_e
+    with span("plan.decompose", coflows=len(demands)):
+        _counters["batches"] += 1
+        out_p: list = [None] * len(demands)
+        out_e: list = [None] * len(demands)
+        buckets: dict[int, list] = {}
+        for i, dem in enumerate(demands):
+            d_full = np.asarray(dem, dtype=np.int64)
+            sub, rows_p, cols_p = support_restrict(d_full)
+            if sub is None:
+                z = np.zeros(0, np.int64)
+                out_p[i] = []
+                out_e[i] = (z, z.copy(), z.copy(), z.copy())
+                continue
+            w = bucket_width(sub.shape[0])
+            buckets.setdefault(w, []).append(
+                (i, sub, rows_p, cols_p, d_full.shape[0]))
+        for w in sorted(buckets):
+            items = buckets[w]
+            subs = [it[1] for it in items]
+            try:
+                res = _decompose_bucket_jit(subs, w)
+            except _BucketOverflow:
+                res = _decompose_bucket_py(subs, w)
+            for (i, _sub, rows_p, cols_p, m_full), (plist, rel) in \
+                    zip(items, res):
+                if rows_p is None:
+                    out_p[i] = plist
+                    out_e[i] = rel
+                else:
+                    out_p[i] = expand_pieces(plist, rows_p, cols_p, m_full)
+                    t0, t1, ss, rr = rel
+                    out_e[i] = (t0, t1, rows_p[ss], cols_p[rr])
+        return out_p, out_e
 
 
 # --------------------------------------------------------------------------

@@ -65,11 +65,13 @@ to the full replan by construction; the repair is counted as a hit when
 at least one block was reused, and per-group reuse counts land in
 ``SessionStats.groups_reused`` / ``groups_replanned``.  Randomized
 G-DM/G-DM-RT always fall back (their delays re-draw per plan).
-Repair/replan counts, the repair hit rate, and warm-replan wall-clock are
-reported in :class:`SessionStats` alongside the engine's BNA/order cache
-stats.  ``repair="legacy"`` keeps the pre-generalization gate (om_alg +
-singleton spread-mode G-DM, whole plan retained at its aligned position)
-for before/after hit-rate comparisons — ``benchmarks/serve_stream.py``
+Repair/replan counts and the repair hit rate are reported in
+:class:`SessionStats` alongside the engine's BNA/order cache stats; the
+time a replan takes is read from the ``session.replan`` /
+``session.repair`` / ``session.plan`` spans (``core/spans.py``).
+``repair="legacy"`` keeps the pre-generalization gate (om_alg + singleton
+spread-mode G-DM, whole plan retained at its aligned position) for
+before/after hit-rate comparisons — ``benchmarks/serve_stream.py``
 reports the delta.
 
 Pinned gamma (``gamma="pinned"``)
@@ -122,13 +124,13 @@ session cannot know whether they decompose demands at all).
 from __future__ import annotations
 
 import math
-import time
 from bisect import insort
 from dataclasses import dataclass, field
 
 import numpy as np
 
 from .result import CompositeSchedule, Transcript
+from .spans import span
 from .types import Coflow, Instance, Job, effective_size, topological_order
 
 __all__ = [
@@ -285,18 +287,10 @@ class SessionStats:
     admission_deferred: int = 0
     admission_rejects: int = 0
     replan_debt: float = 0.0
-    plan_wall_s: float = 0.0
-    first_plan_wall_s: float = 0.0
-    repair_wall_s: float = 0.0
 
     @property
     def repair_hit_rate(self) -> float:
         return self.repairs / self.reschedules if self.reschedules else 0.0
-
-    @property
-    def warm_replan_wall_s(self) -> float:
-        """Wall-clock spent planning after the cold first plan."""
-        return max(self.plan_wall_s - self.first_plan_wall_s, 0.0)
 
     def as_dict(self) -> dict:
         return {
@@ -311,10 +305,6 @@ class SessionStats:
             "admission_deferred": self.admission_deferred,
             "admission_rejects": self.admission_rejects,
             "replan_debt": self.replan_debt,
-            "plan_wall_s": self.plan_wall_s,
-            "first_plan_wall_s": self.first_plan_wall_s,
-            "warm_replan_wall_s": self.warm_replan_wall_s,
-            "repair_wall_s": self.repair_wall_s,
         }
 
 
@@ -716,39 +706,36 @@ class SchedulerSession:
     def _ensure_plan(self) -> None:
         if not self._dirty and self._epoch is not None:
             return
-        sub, cid_maps = sub_instance(self._active, self._remaining,
-                                     self._done, self.m)
-        if not sub.jobs:
-            self._epoch = None
+        with span("session.replan") as sp:
+            sub, cid_maps = sub_instance(self._active, self._remaining,
+                                         self._done, self.m)
+            if not sub.jobs:
+                self._epoch = None
+                self._dirty = False
+                self._arrived_since_plan = []
+                return
+            pinned = None
+            if self._gamma_epoch is not None:
+                pinned = self._gamma_epoch.observe(sub.gamma())
+                self.stats.gamma_rescales = self._gamma_epoch.rescales
+            with span("session.repair"):
+                epoch = self._try_repair(sub, cid_maps, pinned)
+            repaired = epoch is not None
+            if repaired:
+                self.stats.repairs += 1
+            else:
+                with span("session.plan"):
+                    plan, transcript = self._plan(sub, pinned)
+                epoch = self._make_epoch(transcript, plan, cid_maps, sub)
+                self.stats.full_replans += 1
+            sp.set(outcome="repair" if repaired else "full")
+            self._recent_outcomes.append(0 if repaired else 1)
+            del self._recent_outcomes[:-self._recent_window]
+            self.stats.replan_debt = self.replan_debt
+            self.stats.reschedules += 1
+            self._epoch = epoch
             self._dirty = False
             self._arrived_since_plan = []
-            return
-        pinned = None
-        if self._gamma_epoch is not None:
-            pinned = self._gamma_epoch.observe(sub.gamma())
-            self.stats.gamma_rescales = self._gamma_epoch.rescales
-        t0 = time.perf_counter()
-        epoch = self._try_repair(sub, cid_maps, pinned)
-        repaired = epoch is not None
-        if repaired:
-            wall = time.perf_counter() - t0
-            self.stats.repairs += 1
-            self.stats.repair_wall_s += wall
-        else:
-            plan, transcript = self._plan(sub, pinned)
-            wall = time.perf_counter() - t0
-            epoch = self._make_epoch(transcript, plan, cid_maps, sub)
-            self.stats.full_replans += 1
-        self._recent_outcomes.append(0 if repaired else 1)
-        del self._recent_outcomes[:-self._recent_window]
-        self.stats.replan_debt = self.replan_debt
-        self.stats.reschedules += 1
-        self.stats.plan_wall_s += wall
-        if self.stats.reschedules == 1:
-            self.stats.first_plan_wall_s = wall
-        self._epoch = epoch
-        self._dirty = False
-        self._arrived_since_plan = []
 
     def _make_epoch(self, transcript: Transcript, plan,
                     cid_maps: dict[int, list[int]], sub: Instance) -> _Epoch:

@@ -41,6 +41,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from .session import AdmissionPolicy, SchedulerSession
+from .spans import span
 from .traces import dag_edges, sample_coflows
 from .types import Coflow, Job
 
@@ -256,7 +257,8 @@ class StreamDriver:
     def feed(self, job: Job) -> str:
         t0 = time.perf_counter()
         try:
-            return self._feed(job)
+            with span("stream.feed", request=job.jid):
+                return self._feed(job)
         finally:
             self._wall += time.perf_counter() - t0
 

@@ -33,6 +33,8 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from .spans import span
+
 __all__ = [
     "EdgeIntervals",
     "LedgerEntry",
@@ -543,56 +545,59 @@ def merge_and_fix(
     """
     from .backend import compute_alphas, fused_merge_fix
 
-    delays = delays or {}
-    shifted: list[EdgeIntervals] = []
-    for u in units:
-        dt = int(delays.get(u.uid, 0))
-        shifted.append(u.edges.shifted(dt) if dt else u.edges)
-    edges = EdgeIntervals.concat(shifted)
+    with span("plan.merge_fix"):
+        delays = delays or {}
+        shifted: list[EdgeIntervals] = []
+        for u in units:
+            dt = int(delays.get(u.uid, 0))
+            shifted.append(u.edges.shifted(dt) if dt else u.edges)
+        edges = EdgeIntervals.concat(shifted)
 
-    if edges.size:
-        events = np.unique(np.concatenate([edges.t0, edges.t1]))
-    else:
-        events = np.zeros(0, dtype=np.int64)
+        if edges.size:
+            events = np.unique(np.concatenate([edges.t0, edges.t1]))
+        else:
+            events = np.zeros(0, dtype=np.int64)
 
-    force = None if use_kernel is None else ("pallas" if use_kernel else "numpy")
-    fused = fused_merge_fix(events, edges, m, force=force)
-    if fused is not None:
-        alphas, deltas = fused
-        K = alphas.size
-        exp = np.concatenate([[0], np.cumsum(deltas)]).astype(np.float64)
-    else:
-        alphas = compute_alphas(events, edges, m, force=force)
-        K = alphas.size
-        lens = (events[1:] - events[:-1]) if K else np.zeros(0, dtype=np.int64)
-        rates = np.maximum(alphas, 1)
-        exp = np.concatenate([[0], np.cumsum(lens * rates)]).astype(np.float64)
-    # anchor: relative time 0 corresponds to `origin`; the idle lead-in up
-    # to the first event passes at rate 1 (delays / release waits are real)
-    exp += origin + (float(events[0]) if K else 0.0)
+        force = None if use_kernel is None else \
+            ("pallas" if use_kernel else "numpy")
+        fused = fused_merge_fix(events, edges, m, force=force)
+        if fused is not None:
+            alphas, deltas = fused
+            K = alphas.size
+            exp = np.concatenate([[0], np.cumsum(deltas)]).astype(np.float64)
+        else:
+            alphas = compute_alphas(events, edges, m, force=force)
+            K = alphas.size
+            lens = (events[1:] - events[:-1]) if K else np.zeros(0, dtype=np.int64)
+            rates = np.maximum(alphas, 1)
+            exp = np.concatenate([[0], np.cumsum(lens * rates)]).astype(np.float64)
+        # anchor: relative time 0 corresponds to `origin`; the idle lead-in up
+        # to the first event passes at rate 1 (delays / release waits are real)
+        exp += origin + (float(events[0]) if K else 0.0)
 
-    sched = FinalSchedule(
-        m=m,
-        origin=origin,
-        events=events.astype(np.float64) if K else np.zeros(0),
-        alphas=alphas,
-        exp=exp if K else np.zeros(0),
-        ledger=[],
-        merged=edges,
-    )
+        sched = FinalSchedule(
+            m=m,
+            origin=origin,
+            events=events.astype(np.float64) if K else np.zeros(0),
+            alphas=alphas,
+            exp=exp if K else np.zeros(0),
+            ledger=[],
+            merged=edges,
+        )
 
-    # map ledgers through the expansion
-    for u in units:
-        dt = int(delays.get(u.uid, 0))
-        for e in u.ledger:
-            e0 = float(sched.expand_time(e.t0 + dt))
-            e1 = float(sched.expand_time(e.t1 + dt))
-            sched.ledger.append(MappedEntry(e.jid, e.cid, e0, e1, e.srcs, e.dsts, e.units))
+        # map ledgers through the expansion
+        for u in units:
+            dt = int(delays.get(u.uid, 0))
+            for e in u.ledger:
+                e0 = float(sched.expand_time(e.t0 + dt))
+                e1 = float(sched.expand_time(e.t1 + dt))
+                sched.ledger.append(MappedEntry(e.jid, e.cid, e0, e1, e.srcs,
+                                                e.dsts, e.units))
 
-    if decompose:
-        sched.decomposition, sched.exact_completion, sched.coflow_edges = \
-            _decompose(events, edges, alphas, exp, m)
-    return sched
+        if decompose:
+            sched.decomposition, sched.exact_completion, sched.coflow_edges = \
+                _decompose(events, edges, alphas, exp, m)
+        return sched
 
 
 def _decompose(
@@ -617,128 +622,130 @@ def _decompose(
     matching — emit directly without BNA."""
     from .bna import bna
 
-    pieces: list[DecompPiece] = []
-    completion: dict[int, float] = {}
-    seg_t0: list[int] = []
-    seg_t1: list[int] = []
-    seg_s: list[int] = []
-    seg_r: list[int] = []
-    seg_own: list[int] = []
-    seg_jid: list[int] = []
-    seg_cid: list[int] = []
+    with span("plan.nested_decompose"):
+        pieces: list[DecompPiece] = []
+        completion: dict[int, float] = {}
+        seg_t0: list[int] = []
+        seg_t1: list[int] = []
+        seg_s: list[int] = []
+        seg_r: list[int] = []
+        seg_own: list[int] = []
+        seg_jid: list[int] = []
+        seg_cid: list[int] = []
 
-    def emit_seg(t0: int, t1: int, s: int, r: int, key3) -> None:
-        if t1 > t0:
-            seg_t0.append(t0)
-            seg_t1.append(t1)
-            seg_s.append(s)
-            seg_r.append(r)
-            seg_own.append(key3[0])
-            seg_jid.append(key3[1])
-            seg_cid.append(key3[2])
+        def emit_seg(t0: int, t1: int, s: int, r: int, key3) -> None:
+            if t1 > t0:
+                seg_t0.append(t0)
+                seg_t1.append(t1)
+                seg_s.append(s)
+                seg_r.append(r)
+                seg_own.append(key3[0])
+                seg_jid.append(key3[1])
+                seg_cid.append(key3[2])
 
-    def pack() -> EdgeIntervals:
-        return EdgeIntervals(
-            np.asarray(seg_t0, dtype=np.int64),
-            np.asarray(seg_t1, dtype=np.int64),
-            np.asarray(seg_s, dtype=np.int64),
-            np.asarray(seg_r, dtype=np.int64),
-            np.asarray(seg_own, dtype=np.int64),
-            np.asarray(seg_jid, dtype=np.int64),
-            np.asarray(seg_cid, dtype=np.int64),
-        )
+        def pack() -> EdgeIntervals:
+            return EdgeIntervals(
+                np.asarray(seg_t0, dtype=np.int64),
+                np.asarray(seg_t1, dtype=np.int64),
+                np.asarray(seg_s, dtype=np.int64),
+                np.asarray(seg_r, dtype=np.int64),
+                np.asarray(seg_own, dtype=np.int64),
+                np.asarray(seg_jid, dtype=np.int64),
+                np.asarray(seg_cid, dtype=np.int64),
+            )
 
-    if edges.size == 0:
+        if edges.size == 0:
+            return pieces, completion, pack()
+        K = alphas.size
+        si = np.searchsorted(events, edges.t0)
+        ei = np.searchsorted(events, edges.t1)
+        add_at: list[list[int]] = [[] for _ in range(K + 1)]
+        rem_at: list[list[int]] = [[] for _ in range(K + 1)]
+        for i in range(edges.size):
+            add_at[si[i]].append(i)
+            rem_at[ei[i]].append(i)
+        # per edge: ordered list of (activation_seq, (owner, jid, cid), mult)
+        active: dict[tuple[int, int], list] = {}
+        seq = 0
+        for k in range(K):
+            for i in rem_at[k]:
+                key = (int(edges.s[i]), int(edges.r[i]))
+                k3 = (int(edges.owner[i]), int(edges.jid[i]), int(edges.cid[i]))
+                lst = active[key]
+                for j, ent in enumerate(lst):
+                    if ent[1] == k3:
+                        if ent[2] == 1:
+                            lst.pop(j)
+                        else:
+                            ent[2] -= 1
+                        break
+                if not lst:
+                    del active[key]
+            for i in add_at[k]:
+                key = (int(edges.s[i]), int(edges.r[i]))
+                k3 = (int(edges.owner[i]), int(edges.jid[i]), int(edges.cid[i]))
+                lst = active.setdefault(key, [])
+                for ent in lst:
+                    if ent[1] == k3:
+                        ent[2] += 1
+                        break
+                else:
+                    lst.append([seq, k3, 1])
+                    seq += 1
+            if not active:
+                continue
+            l = int(events[k + 1] - events[k])
+            if l == 0:
+                continue
+            t_exp = int(round(exp[k]))
+            a = int(alphas[k])
+            srcs = np.array([s for s, _ in active], dtype=np.int64)
+            dsts = np.array([r for _, r in active], dtype=np.int64)
+            cnts = np.array([sum(e[2] for e in lst) for lst in active.values()],
+                            dtype=np.int64)
+            # FIFO queues for this interval: per edge, units in activation order
+            queues = {key: [[k3, mult * l] for _, k3, mult in sorted(lst)]
+                      for key, lst in active.items()}
+            if a <= 1:
+                pieces.append(DecompPiece(t_exp, l, srcs, dsts,
+                                          np.ones_like(cnts)))
+                end = float(t_exp + l)
+                for key, q in queues.items():
+                    cursor = t_exp
+                    for k3, amt in q:
+                        emit_seg(cursor, cursor + amt, key[0], key[1], k3)
+                        cursor += amt
+                        completion[k3[0]] = max(completion.get(k3[0], 0.0), end)
+                continue
+            dm = np.zeros((m, m), dtype=np.int64)
+            dm[srcs, dsts] = cnts * l
+            off = 0
+            for dur, match in bna(dm):
+                ss = np.flatnonzero(match >= 0)
+                pieces.append(DecompPiece(t_exp + off, int(dur), ss, match[ss],
+                                          np.ones(ss.size, dtype=np.int64)))
+                piece_end = float(t_exp + off + int(dur))
+                for s_ in ss:
+                    key = (int(s_), int(match[s_]))
+                    q = queues.get(key)
+                    if not q:
+                        continue
+                    served = int(dur)
+                    used = 0
+                    while served > 0 and q:
+                        k3, rem = q[0]
+                        take = min(rem, served)
+                        rem -= take
+                        served -= take
+                        emit_seg(t_exp + off + used, t_exp + off + used + take,
+                                 key[0], key[1], k3)
+                        used += take
+                        if rem == 0:
+                            q.pop(0)
+                        else:
+                            q[0][1] = rem
+                        completion[k3[0]] = max(completion.get(k3[0], 0.0),
+                                                piece_end)
+                off += int(dur)
+            assert off == l * a, "fix-up BNA length mismatch"
         return pieces, completion, pack()
-    K = alphas.size
-    si = np.searchsorted(events, edges.t0)
-    ei = np.searchsorted(events, edges.t1)
-    add_at: list[list[int]] = [[] for _ in range(K + 1)]
-    rem_at: list[list[int]] = [[] for _ in range(K + 1)]
-    for i in range(edges.size):
-        add_at[si[i]].append(i)
-        rem_at[ei[i]].append(i)
-    # per edge: ordered list of (activation_seq, (owner, jid, cid), mult)
-    active: dict[tuple[int, int], list] = {}
-    seq = 0
-    for k in range(K):
-        for i in rem_at[k]:
-            key = (int(edges.s[i]), int(edges.r[i]))
-            k3 = (int(edges.owner[i]), int(edges.jid[i]), int(edges.cid[i]))
-            lst = active[key]
-            for j, ent in enumerate(lst):
-                if ent[1] == k3:
-                    if ent[2] == 1:
-                        lst.pop(j)
-                    else:
-                        ent[2] -= 1
-                    break
-            if not lst:
-                del active[key]
-        for i in add_at[k]:
-            key = (int(edges.s[i]), int(edges.r[i]))
-            k3 = (int(edges.owner[i]), int(edges.jid[i]), int(edges.cid[i]))
-            lst = active.setdefault(key, [])
-            for ent in lst:
-                if ent[1] == k3:
-                    ent[2] += 1
-                    break
-            else:
-                lst.append([seq, k3, 1])
-                seq += 1
-        if not active:
-            continue
-        l = int(events[k + 1] - events[k])
-        if l == 0:
-            continue
-        t_exp = int(round(exp[k]))
-        a = int(alphas[k])
-        srcs = np.array([s for s, _ in active], dtype=np.int64)
-        dsts = np.array([r for _, r in active], dtype=np.int64)
-        cnts = np.array([sum(e[2] for e in lst) for lst in active.values()],
-                        dtype=np.int64)
-        # FIFO queues for this interval: per edge, units in activation order
-        queues = {key: [[k3, mult * l] for _, k3, mult in sorted(lst)]
-                  for key, lst in active.items()}
-        if a <= 1:
-            pieces.append(DecompPiece(t_exp, l, srcs, dsts, np.ones_like(cnts)))
-            end = float(t_exp + l)
-            for key, q in queues.items():
-                cursor = t_exp
-                for k3, amt in q:
-                    emit_seg(cursor, cursor + amt, key[0], key[1], k3)
-                    cursor += amt
-                    completion[k3[0]] = max(completion.get(k3[0], 0.0), end)
-            continue
-        dm = np.zeros((m, m), dtype=np.int64)
-        dm[srcs, dsts] = cnts * l
-        off = 0
-        for dur, match in bna(dm):
-            ss = np.flatnonzero(match >= 0)
-            pieces.append(DecompPiece(t_exp + off, int(dur), ss, match[ss],
-                                      np.ones(ss.size, dtype=np.int64)))
-            piece_end = float(t_exp + off + int(dur))
-            for s_ in ss:
-                key = (int(s_), int(match[s_]))
-                q = queues.get(key)
-                if not q:
-                    continue
-                served = int(dur)
-                used = 0
-                while served > 0 and q:
-                    k3, rem = q[0]
-                    take = min(rem, served)
-                    rem -= take
-                    served -= take
-                    emit_seg(t_exp + off + used, t_exp + off + used + take,
-                             key[0], key[1], k3)
-                    used += take
-                    if rem == 0:
-                        q.pop(0)
-                    else:
-                        q[0][1] = rem
-                    completion[k3[0]] = max(completion.get(k3[0], 0.0),
-                                            piece_end)
-            off += int(dur)
-        assert off == l * a, "fix-up BNA length mismatch"
-    return pieces, completion, pack()
