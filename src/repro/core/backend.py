@@ -691,6 +691,9 @@ def cache_stats() -> dict:
              "loads": loads_cache.stats(),
              "gkey": {**gkey_cache.stats(), "prefix": dict(_gkey_counts)},
              "launches": launch_counts()}
+    merge_fix = sys.modules.get("repro.kernels.merge_fix.ops")
+    stats["merge_fix"] = (merge_fix.merge_fix_stats() if merge_fix
+                          else {"calls": 0, "misses": 0})
     if "repro.core.pipeline" in sys.modules:
         stats["plan"] = sys.modules["repro.core.pipeline"].pipeline_stats()
     return stats

@@ -245,7 +245,7 @@ def _resolve_step(force: str | None, B: int, w: int, D_max: int):
     are at most `D_max`, per the REPRO_BNA_BACKEND dispatch (see
     backend.py); None runs the numpy step.  A bucket past the kernel's
     int32 range guards stays on the numpy step, exactly as
-    ``coflow_merge.ops.kernel_alphas`` keeps its alphas on the reference:
+    ``coflow_merge.ops.padded_alphas`` keeps its alphas on the reference:
     that is an exactness guard, not a device fallback."""
     from .backend import resolve_bna_backend
 

@@ -36,8 +36,8 @@ import contextlib
 import time
 from dataclasses import dataclass, field
 
-__all__ = ["NAMES", "Span", "Compile", "Recorder", "span", "recording",
-           "start", "stop"]
+__all__ = ["NAMES", "Span", "Compile", "Recorder", "span", "annotate",
+           "recording", "start", "stop"]
 
 # the stable span names, one per layer boundary (PERF.md, "Layers")
 NAMES = ("stream.feed", "session.replan", "session.repair", "session.plan",
@@ -192,6 +192,13 @@ def span(name: str, request=None, **attrs):
     if _rec is None:
         return _NULL
     return _Open(_rec, name, request, attrs)
+
+
+def annotate(**attrs) -> None:
+    """Attributes for the innermost open span, from code below the layer
+    that opened it; nothing while nothing records."""
+    if _rec is not None and _rec._stack:
+        _rec.spans[_rec._stack[-1]].attrs.update(attrs)
 
 
 def start(annotate: bool = False) -> Recorder:

@@ -48,19 +48,26 @@ def kernel_alphas(delta: jax.Array, *, block_k: int,
                   interpret: bool) -> jax.Array:
     """(K,) int32 alphas of a (K, ports) delta array through the kernel:
     K is padded to a power-of-two block (at most `block_k`, at least 8)
-    and ports to the 128-lane multiple.  Shared by `interval_alphas` and
-    the fused merge_fix step."""
+    and ports to the 128-lane multiple, for `interval_alphas`."""
     K, ports = delta.shape
     bk = min(block_k, max(8, 1 << (K - 1).bit_length()))
-    k_pad = (-K) % bk
-    p_pad = (-ports) % 128
+    dpad = jnp.pad(delta, ((0, (-K) % bk), (0, (-ports) % 128)))
+    return padded_alphas(dpad, block_k=bk, interpret=interpret)[:K, 0]
+
+
+def padded_alphas(dpad: jax.Array, *, block_k: int,
+                  interpret: bool) -> jax.Array:
+    """(K_pad, 1) int32 alphas of a delta array already padded to whole
+    `block_k` tiles and 128 lanes, with no slice back to an exact K: one
+    counted kernel launch, or the jnp reference past the kernel's int32
+    index range.  The fused merge_fix step calls this on bucketed shapes."""
+    rows, lanes = dpad.shape
     # Pallas indexes the padded delta with int32 arithmetic; past that the
     # jnp reference (64-bit indexing) is the only correct path.
-    if (K + k_pad) * (ports + p_pad) >= _I32_MAX:
-        return alphas_ref(delta)
+    if rows * lanes >= _I32_MAX:
+        return alphas_ref(dpad)[:, None]
     count_launch("coflow_merge")
-    dpad = jnp.pad(delta, ((0, k_pad), (0, p_pad)))
-    return coflow_merge_padded(dpad, block_k=bk, interpret=interpret)[:K, 0]
+    return coflow_merge_padded(dpad, block_k=block_k, interpret=interpret)
 
 
 def edge_interval_alphas(

@@ -66,15 +66,27 @@ def _compile(fn, *avals):
 
 @pytest.mark.parametrize("K", [8, 4096, 65536])
 def test_coflow_merge_compiles_for_v5e(one_chip, K):
-    """The merge_and_fix alpha kernel as both dispatch sites call it
-    (``interval_alphas`` and the fused ``merge_fix`` step share
-    ``kernel_alphas``): (K, 2m) deltas padded to a (K_pad, 384) tile,
-    up to ``coflow_merge_padded`` at (65536, 384)."""
+    """The merge_and_fix alpha kernel as ``interval_alphas`` calls it
+    (the fused ``merge_fix`` step reaches the same ``padded_alphas``):
+    (K, 2m) deltas padded to a (K_pad, 384) tile, up to
+    ``coflow_merge_padded`` at (65536, 384)."""
     from repro.kernels.coflow_merge.ops import kernel_alphas
 
     compiled, _ = _compile(
         lambda d: kernel_alphas(d, block_k=1024, interpret=False),
         _i32((K, PORTS_150), one_chip))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_merge_fix_programs_fit_one_v5e(one_chip):
+    """The fused merge_fix step's one device program at the largest bucket
+    the 150-port cell reaches (K_pad 16384): ``padded_alphas`` on the
+    host-built (K_pad, 384) delta tile, no slice back to an exact K."""
+    from repro.kernels.coflow_merge.ops import padded_alphas
+
+    compiled, _ = _compile(
+        lambda d: padded_alphas(d, block_k=1024, interpret=False),
+        _i32((16384, 384), one_chip))
     assert "tpu_custom_call" in compiled.as_text()
 
 

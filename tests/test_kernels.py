@@ -181,3 +181,64 @@ def test_merge_fix_step_empty_and_int64_lens():
     ral, rde = merge_fix_ref(events, t0, t1, s, r, 2)
     assert np.array_equal(al, ral) and np.array_equal(de, rde)
     assert de.dtype == np.int64 and de.max() > 2**31
+
+
+def _bucket_edge_case(E, K, rng):
+    """E activations over exactly K intervals on 150 ports, one of them
+    ending on the last event (``ei == K``) and one starting on the first."""
+    events = np.cumsum(rng.integers(1, 40, K + 1)) - 1
+    i0 = rng.integers(0, K, E)
+    i1 = np.minimum(i0 + rng.integers(1, 6, E), K)
+    i0[0], i1[0] = 0, K
+    i1[-1] = K
+    m = 150
+    return (events.astype(np.int64), events[i0], events[i1],
+            rng.integers(0, m, E), rng.integers(0, m, E), m)
+
+
+@pytest.mark.parametrize("use_kernel", [True, False])
+@pytest.mark.parametrize("K", [7, 8, 9, 1023, 1024, 1025])
+@pytest.mark.parametrize("E", [127, 128, 129, 255, 256, 257])
+def test_merge_fix_step_exact_across_buckets(E, K, use_kernel):
+    """Exact E and K on either side of a power of two land in different
+    padded buckets; the padded activations and intervals must change no
+    bit of what is read back."""
+    from repro.kernels.merge_fix import merge_fix_step
+    from repro.kernels.merge_fix.ref import merge_fix_ref
+
+    events, t0, t1, s, r, m = _bucket_edge_case(
+        E, K, np.random.default_rng(E * 10007 + K))
+    assert np.searchsorted(events, t1).max() == K
+    al, de = merge_fix_step(events, t0, t1, s, r, m, use_kernel=use_kernel)
+    ral, rde = merge_fix_ref(events, t0, t1, s, r, m)
+    assert al.dtype == de.dtype == np.int64
+    assert np.array_equal(al, ral) and np.array_equal(de, rde)
+
+
+def test_merge_fix_step_compiles_once_per_bucket():
+    """Two calls with different exact (E, K) inside one K_pad bucket build
+    one program: the second compiles nothing.  A call in a new bucket
+    builds exactly one more."""
+    from repro.core import cache_stats, spans
+    from repro.kernels.merge_fix import merge_fix_step
+    from repro.kernels.merge_fix.ref import merge_fix_ref
+
+    rng = np.random.default_rng(5)
+    m = 450                # 1024 lanes: a kernel width no other test uses
+    cases = [(140, 20), (500, 30), (300, 40)]     # K_pad 32, 32, 64
+    runs = []
+    for E, K in cases:
+        events, t0, t1, s, r, _ = _bucket_edge_case(E, K, rng)
+        before = cache_stats()["merge_fix"]
+        with spans.recording() as rec, spans.span("plan.merge_fix"):
+            got = merge_fix_step(events, t0, t1, s, r, m)
+        after = cache_stats()["merge_fix"]
+        assert all(np.array_equal(a, b) for a, b in
+                   zip(got, merge_fix_ref(events, t0, t1, s, r, m)))
+        runs.append((after["calls"] - before["calls"],
+                     after["misses"] - before["misses"], len(rec.compiles),
+                     rec.spans[0].attrs["k_pad"]))
+    assert [c for c, _, _, _ in runs] == [1, 1, 1]
+    assert [b for _, b, _, _ in runs] == [1, 0, 1]
+    assert runs[0][2] > 0 and runs[1][2] == 0 and runs[2][2] > 0
+    assert [k for _, _, _, k in runs] == [32, 32, 64]

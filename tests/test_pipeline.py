@@ -111,6 +111,29 @@ def test_plan_identity_jit_pallas_stack(scen, sched):
     assert _fingerprint(p) == ref, f"{scen}/{sched}: pallas stack diverged"
 
 
+def test_fused_merge_fix_reports_its_bucket():
+    """Under the jit + pallas stack each merge_and_fix with edges runs the
+    fused step: its ``plan.merge_fix`` span carries the power-of-two K_pad
+    bucket the kernel ran on, and ``cache_stats()["merge_fix"]`` counts
+    one call per such span."""
+    from repro.core import spans
+
+    built = _tiny("incast")
+    with use_plan_backend("jit"), backend.use_alpha_backend("pallas"), \
+            backend.use_bna_backend("pallas"):
+        clear_caches()
+        before = cache_stats()["merge_fix"]
+        with spans.recording() as rec:
+            plan(built.instance, "gdm", seed=0)
+        after = cache_stats()["merge_fix"]
+    fused = [s.attrs for s in rec.spans
+             if s.name == "plan.merge_fix" and "k_pad" in s.attrs]
+    assert fused and after["calls"] - before["calls"] == len(fused)
+    assert after["misses"] - before["misses"] <= len(fused)
+    for a in fused:
+        assert a["k_pad"] >= 8 and not a["k_pad"] & (a["k_pad"] - 1)
+
+
 # --------------------------------------------------------------------------
 # decomposition bit-identity: padding / width-bucket edge cases
 # --------------------------------------------------------------------------

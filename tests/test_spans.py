@@ -259,3 +259,17 @@ def test_every_span_sits_at_its_layer_boundary_outside_jit():
             found.add((rel, fn.name, name))
     assert found == WHERE
     assert {name for _, _, name in WHERE} == set(spans.NAMES)
+
+
+def test_annotate_sets_the_innermost_open_span():
+    """``annotate`` hands attributes from code below a layer to the span
+    that layer opened, the innermost one open; off, it does nothing."""
+    spans.annotate(k_pad=8)                  # off: no recording to touch
+    with spans.recording() as rec:
+        with spans.span("plan.merge_fix", K=3):
+            spans.annotate(k_pad=16)
+            with spans.span("plan.decompose"):
+                spans.annotate(coflows=2)
+        spans.annotate(k_pad=32)             # no span open
+    assert rec.spans[0].attrs == {"K": 3, "k_pad": 16}
+    assert rec.spans[1].attrs == {"coflows": 2}
