@@ -7,6 +7,11 @@ A driver (``bench/drivers/<name>.py``) gets a ``Context`` and returns a
 ``ctx.span(name)``, and records each answered request with
 ``ctx.answered(due, done)``.  The metric readers
 (``bench/metrics/<name>.py``) read only the ``Run``.
+
+In a traced run the program's own spans (``repro.core.spans``) record
+from the window's open to its close, and the recorder is kept as
+``Run.program``.  They stay off the profile (no annotations), and
+untraced runs, which give the end-to-end metrics, never record them.
 """
 from __future__ import annotations
 
@@ -49,6 +54,7 @@ class Run:
     memory_peak_bytes: int | None = None
     notes: dict = field(default_factory=dict)
     trace: object = None                          # lib.trace.Reduced
+    program: object = None                        # repro.core.spans.Recorder
 
     @property
     def correct(self) -> bool:
@@ -104,8 +110,17 @@ class Context:
             yield
         self.run.spans.append((name, t0, time.perf_counter()))
 
+    def records_program_spans(self) -> bool:
+        """Whether the program's spans record in the window."""
+        return self.trace_dir is not None
+
+    def annotates_program_spans(self) -> bool:
+        """Whether that recording also writes them into the profile."""
+        return False
+
     def open_window(self) -> None:
-        """Set-up ends here; in a traced run the profiler starts."""
+        """Set-up ends here; in a traced run the profiler starts, and the
+        program's spans record."""
         self._window_ann = None
         if self.trace_dir is not None:
             import jax
@@ -123,6 +138,10 @@ class Context:
         if self._window_ann is not None:
             self.run.notes["window_anchor"] = time.perf_counter()
             self._window_ann.__enter__()
+        if self.records_program_spans():
+            from repro.core import spans
+
+            spans.start(annotate=self.annotates_program_spans())
 
     def window_open(self) -> bool:
         return time.perf_counter() < self._deadline
@@ -145,6 +164,10 @@ class Context:
         import jax
         import jax.monitoring as monitoring
 
+        if self.records_program_spans():
+            from repro.core import spans
+
+            self.run.program = spans.stop()
         if self.trace_dir is not None:
             self._window_ann.__exit__(None, None, None)
             t0 = time.perf_counter()

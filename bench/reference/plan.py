@@ -167,7 +167,8 @@ def dma_rt(jobs: list[Job], m: int, origin: int, pieces: Pieces) -> Merged:
                             pieces(c.demand))
             u.uid = cid
             parts.append(u)
-        units.append(merge_and_fix(parts, m, decompose=True).to_unit(j.jid))
+        units.append(merge_and_fix(parts, m, decompose=True, pieces=pieces)
+                     .to_unit(j.jid))
     delta = aggregate_size(c.demand for j in jobs for c in j.coflows)
     delays = dict(zip([j.jid for j in jobs],
                       spread(len(jobs), int(delta // BETA))))

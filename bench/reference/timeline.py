@@ -9,6 +9,10 @@ through the stretch; completions and execution read the ledger.  With
 ``decompose=True`` each stretched interval is also decomposed (BNA on
 l_I times the merged counts) and each unit's last packet is found by a
 FIFO walk, which is what nesting a DMA-SRT schedule into DMA-RT needs.
+The decomposition is a pure function of the interval's matrix, so a
+caller that replans the same jobs again and again passes a memo
+(``plan.Pieces``) as ``pieces``, and each distinct matrix is decomposed
+once.
 """
 from __future__ import annotations
 
@@ -166,7 +170,8 @@ def alphas(events: np.ndarray, edges: Edges, m: int) -> np.ndarray:
 
 
 def merge_and_fix(units: list[Unit], m: int, delays: dict | None = None,
-                  origin: int = 0, decompose: bool = False) -> Merged:
+                  origin: int = 0, decompose: bool = False,
+                  pieces=bna) -> Merged:
     delays = delays or {}
     edges = Edges.concat([u.edges.shifted(int(delays.get(u.uid, 0)))
                           for u in units])
@@ -188,14 +193,15 @@ def merge_and_fix(units: list[Unit], m: int, delays: dict | None = None,
                 out.expand_time(e.t1 + dt), e.srcs, e.dsts, e.units))
     if decompose:
         out.exact_completion, out.coflow_edges = _decompose(
-            events, edges, al, exp, m)
+            events, edges, al, exp, m, pieces)
     return out
 
 
-def _decompose(events, edges: Edges, al, exp, m):
-    """Per stretched interval, BNA on l_I times the merged counts; each
-    edge's units are served FIFO in activation order.  Returns each unit's
-    last-packet time and the served stretches as edge intervals."""
+def _decompose(events, edges: Edges, al, exp, m, pieces):
+    """Per stretched interval, BNA (through ``pieces``) on l_I times the
+    merged counts; each edge's units are served FIFO in activation order.
+    Returns each unit's last-packet time and the served stretches as edge
+    intervals."""
     completion: dict[int, float] = {}
     segs: list[tuple] = []
     if edges.size == 0:
@@ -262,7 +268,7 @@ def _decompose(events, edges: Edges, al, exp, m):
         dm = np.zeros((m, m), dtype=np.int64)
         dm[srcs, dsts] = cnts * ln
         off = 0
-        for dur, match in bna(dm):
+        for dur, match in pieces(dm):
             piece_end = float(t_exp + off + int(dur))
             for s_ in np.flatnonzero(match >= 0):
                 key = (int(s_), int(match[s_]))

@@ -30,20 +30,14 @@ from bench.lib import trace as tr  # noqa: E402
 
 
 class RecordingContext(harness.Context):
-    """The harness's context, with the program's recording on in the
-    window."""
+    """The harness's context, with the program's recording on in every
+    window, and in the profile when traced."""
 
-    def open_window(self) -> None:
-        from repro.core import spans
+    def records_program_spans(self) -> bool:
+        return True
 
-        super().open_window()
-        spans.start(annotate=self.trace_dir is not None)
-
-    def close_window(self) -> None:
-        from repro.core import spans
-
-        self.run.program = spans.stop()
-        super().close_window()
+    def annotates_program_spans(self) -> bool:
+        return self.trace_dir is not None
 
 
 def measure(bench: dict, cell: dict, seed: int, seconds: float,

@@ -22,8 +22,7 @@ from bench.lib.harness import ROOT, load_json
 dma = importlib.import_module("repro.core.dma")
 dma_srt = importlib.import_module("repro.core.dma_srt")
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
-# every cell, and the G-DM-RT configuration that waits for its cell
-CELLS = [w["name"] for w in BENCH["workloads"]] + ["fb150_gdm_rt.arrivals"]
+CELLS = [w["name"] for w in BENCH["workloads"]]
 
 
 def _measure(cell_name: str, seed: int = 2**31 + 5):

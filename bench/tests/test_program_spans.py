@@ -6,6 +6,7 @@ per-layer reader on a hand-built run; and a whole run at a small size on
 the CPU with the recording on."""
 import gzip
 import json
+import time
 
 import jax
 import pytest
@@ -14,7 +15,8 @@ from jax.profiler import ProfileData
 import repro.core as core
 from bench.lib import program_spans as ps
 from bench.lib import trace
-from bench.lib.harness import ROOT, Run, load_json
+from bench.lib.harness import ROOT, Context, Run, load_json
+from bench.metrics import nested_decompose_ms
 from bench.tests import spans_at_size
 from bench.tests.test_trace import DATA, _Ev, _Line, _Plane, _Profile
 from repro.core.spans import Compile, Recorder, Span
@@ -187,6 +189,54 @@ def test_notes_by_hand():
     # (1.3 - 0.5 - 0.2) + (0.5 - 0.4)
     assert notes["time_by_span"]["plan.merge_fix"] == {
         "n": 2, "self_s": pytest.approx(0.7), "compile_s": pytest.approx(0.6)}
+
+
+def test_nested_decompose_ms_by_hand():
+    S = Span
+    spans = [
+        S("stream.feed", 0.0, 5.0, None, 1),
+        S("plan.merge_fix", 0.5, 3.5, 0, 1),
+        S("plan.nested_decompose", 1.0, 3.0, 1, 1),
+        S("plan.nested_decompose", 1.5, 2.0, 2, 1),    # inside the one above
+        S("stream.feed", 5.0, 9.0, None, 2),
+        S("plan.nested_decompose", 6.0, 6.5, 4, 2),
+        S("stream.feed", 10.5, 11.5, None, 3),         # after the window
+        S("plan.nested_decompose", 10.6, 11.4, 6, 3),
+    ]
+    run = Run(window=(0.0, 10.0), requests=[(0.0, 5.0), (5.0, 10.0)])
+    assert nested_decompose_ms.read(run) is None    # no recording
+    run.program = Recorder(spans=spans)
+    assert nested_decompose_ms.read(run) == pytest.approx(2.5 / 2 * 1e3)
+    run.program = Recorder(spans=[spans[0], spans[4]])
+    assert nested_decompose_ms.read(run) == 0.0     # no nested step
+
+
+@pytest.mark.parametrize("context,traced,records,annotates", [
+    (Context, False, False, False),
+    (Context, True, True, False),
+    (spans_at_size.RecordingContext, False, True, False),
+    (spans_at_size.RecordingContext, True, True, True),
+])
+def test_window_records_the_program_spans(context, traced, records,
+                                          annotates, tmp_path):
+    """The harness records the program's spans in traced windows only,
+    off the profile; ``spans_at_size`` records in every window, in the
+    profile when traced, and never starts a second recording."""
+    ctx = context({}, {"trace_requests": 1}, 0, 60.0,
+                  tmp_path if traced else None, 0.0)
+    ctx.open_window()
+    rec = core.spans._rec
+    assert (rec is not None) == records
+    with core.spans.span("plan.nested_decompose"):
+        pass
+    now = time.perf_counter()
+    assert ctx.answered(now, now)
+    ctx.close_window()
+    assert core.spans._rec is None
+    assert ctx.run.program is rec
+    if records:
+        assert rec.annotate == annotates
+        assert [s.name for s in rec.spans] == ["plan.nested_decompose"]
 
 
 # --- a whole run with the recording on ----------------------------------------
