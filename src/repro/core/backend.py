@@ -478,8 +478,12 @@ def bna_pieces(demand: np.ndarray) -> list:
 def bna_pieces_many(demands: list, keys: list | None = None) -> list:
     """BNA decompositions for a whole batch of demands: the LRU is
     consulted first, and ONLY the misses (deduplicated — repeated demands
-    in one batch decompose once) go through the batched ``bna_many``
-    decomposition in a single call.  Results are bit-identical to
+    in one batch decompose once) go through one batched decomposition:
+    the compiled width buckets of ``core/pipeline.py`` when the plan
+    backend resolves jit (with its per-bucket int32 fallback), else
+    ``bna_many``.  It opens no span: the coflow prefetch
+    (``prefetch_bna``) and the nested step (``timeline._decompose``) each
+    hold its time in their own.  Results are bit-identical to
     ``[bna_pieces(d) for d in demands]``; per-batch hit/miss counts land in
     ``cache_stats()["bna"]["batch"]``.  ``keys`` accepts precomputed
     ``_bna_key`` values (same order as ``demands``) so callers that
@@ -508,7 +512,11 @@ def bna_pieces_many(demands: list, keys: list | None = None) -> list:
         else:
             slot.append(i)
     if miss_demands:
-        with span("plan.decompose", coflows=len(miss_demands)):
+        if resolve_plan_backend() == "jit":
+            from . import pipeline
+
+            decomposed = pipeline.decompose_pieces(miss_demands)
+        else:
             decomposed = bna_many(miss_demands)
         for key, pieces in zip(miss_keys, decomposed):
             bna_cache.store(key, pieces)
@@ -541,7 +549,8 @@ def prefetch_bna(demands: "Iterable[np.ndarray]") -> None:
     keys = [_bna_key(d) for d in ds]
     if len(set(keys)) > config.bna_cache_size:
         return
-    bna_pieces_many(ds, keys=keys)
+    with span("plan.decompose", coflows=len(ds)):
+        bna_pieces_many(ds, keys=keys)
 
 
 # --------------------------------------------------------------------------

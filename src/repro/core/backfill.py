@@ -41,6 +41,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from .result import CompositeSchedule, Transcript, TranscriptEntry, twct
+from .spans import annotate, span
 from .types import Instance, parents_of, topological_order
 
 __all__ = ["backfill", "BackfillResult"]
@@ -78,7 +79,11 @@ def backfill(sched: CompositeSchedule, fill: bool = True,
     behavior, kept as a comparator).  fill=False disables step 2 (filling)
     in either executor: for packet that is an exact replay of the plan, for
     ledger it is the *null-backfill* monotonicity comparator (see module
-    docstring for why ledger window-ends are not pointwise comparable)."""
+    docstring for why ledger window-ends are not pointwise comparable).
+
+    The call is the span ``plan.backfill``; the packet executor tags it
+    with its elementary ``intervals``, the plan's ``segments`` and the
+    step-2 ``fills`` it made."""
     sched = getattr(sched, "schedule", sched)
     if isinstance(sched, BackfillResult):
         raise ValueError(
@@ -88,9 +93,10 @@ def backfill(sched: CompositeSchedule, fill: bool = True,
     if exec not in _EXECUTORS:
         raise ValueError(f"unknown backfill executor {exec!r}; "
                          f"choose from {_EXECUTORS}")
-    if exec == "packet":
-        return _packet_sweep(sched, fill)
-    return _ledger_sweep(sched, fill)
+    with span("plan.backfill"):
+        if exec == "packet":
+            return _packet_sweep(sched, fill)
+        return _ledger_sweep(sched, fill)
 
 
 # --------------------------------------------------------------------------
@@ -225,6 +231,7 @@ def _packet_sweep(sched: CompositeSchedule, fill: bool) -> BackfillResult:
         add_at = rem_at = []
 
     active: set[int] = set()
+    fills = 0
     for k in range(K):
         for i in rem_at[k]:
             active.discard(i)
@@ -288,6 +295,7 @@ def _packet_sweep(sched: CompositeSchedule, fill: bool) -> BackfillResult:
                 f.rem_total = float(f.rem.sum())
                 out.append(TranscriptEntry(f.jid, f.cid, a, b,
                                            f.srcs, f.dsts, amount))
+                fills += 1
                 if f.rem_total <= 1e-9:
                     comp[key] = b
                     newly.append(key)
@@ -303,6 +311,7 @@ def _packet_sweep(sched: CompositeSchedule, fill: bool) -> BackfillResult:
     # executor finishes no later than the plan, pointwise
     assert all(f.rem_total <= 1e-6 for f in plan.values()), \
         "packet backfill lost demand"
+    annotate(intervals=K, segments=segs.size, fills=fills)
     _stamp_zero_demand(inst, parents, topo, is_zero, comp, out)
     return _finalize(inst, comp, out, "packet")
 

@@ -64,6 +64,7 @@ from .spans import span
 
 __all__ = [
     "prefetch_demands",
+    "decompose_pieces",
     "coflow_edges_rel",
     "instance_load_vectors",
     "edge_cache",
@@ -77,6 +78,15 @@ _INT32_MAX = int(np.iinfo(np.int32).max)
 #: per-program cap on the (lanes, T_cap, w) int32 piece stack a compiled
 #: decomposition returns (a v5e chip holds 16 GB of HBM)
 _PIECES_BYTES = 1 << 30
+
+#: interval programs (``decompose_pieces``) run fixed lanes: the (lanes,
+#: w, w) int32 demand stack a program steps is at most this large, a
+#: program has at most ``_FIXED_LANES_MAX`` lanes, and no bucket is
+#: narrower than ``_FIXED_MIN_WIDTH`` (padding ports carry no load, so the
+#: pieces stay)
+_FIXED_STEP_BYTES = 1 << 21
+_FIXED_LANES_MAX = 256
+_FIXED_MIN_WIDTH = 8
 
 #: demand key -> (t0, t1, s, r) int64 *relative* edge intervals (start = 0);
 #: the jit-path replacement for re-running the Python RLE per plan.
@@ -267,6 +277,18 @@ def _build_decompose(w: int, T_cap: int):
     return decompose
 
 
+def _build_decompose_intervals(w: int, T_cap: int):
+    """``_build_decompose``'s program under a name of its own, so the
+    device trace keeps the nested step's time (``jit_decompose_intervals``)
+    apart from the coflow decomposition's (``jit_decompose``)."""
+    decompose = _build_decompose(w, T_cap)
+
+    def decompose_intervals(d, ks):
+        return decompose(d, ks)
+
+    return decompose_intervals
+
+
 def _get_compiled(key: tuple, builder, avals) -> object:
     """AOT-compile `builder()` for the given input avals, LRU-cached on
     `key` (the compile cache is what makes repeated plans pay tracing and
@@ -331,7 +353,7 @@ class _BucketOverflow(Exception):
     """Bucket loads exceed int32 — decompose it on the numpy path."""
 
 
-def _decompose_bucket_jit(subs: list[np.ndarray], w: int):
+def _decompose_bucket_jit(subs: list[np.ndarray], w: int, intervals: bool):
     """Decompose one width bucket through the compiled path; returns per
     matrix ``(pieces_restricted, (t0, t1, s, r) restricted rel-edges)``.
 
@@ -340,7 +362,15 @@ def _decompose_bucket_jit(subs: list[np.ndarray], w: int):
     ``_PIECES_BYTES``: a chip-size bucket (m = 150 lands half the trace's
     coflows at w = 256, with T_cap up to 32768, 32 MiB per lane) would
     otherwise need more HBM than one chip has.  Lanes are independent, so chunking cannot
-    change any lane's steps."""
+    change any lane's steps.
+
+    ``intervals`` marks the merged-interval matrices of
+    ``timeline._decompose``: they run their own programs,
+    ``jit_decompose_intervals``, and pad every chunk to one lane count per
+    (w, T_cap), ``_fixed_lanes``, instead of the next power of two of its
+    size, since they come in batches of every size and one program per
+    (w, T_cap) (w at least ``_FIXED_MIN_WIDTH``) keeps a replan from
+    meeting a new program's compile."""
     nnz = max(int((s > 0).sum()) for s in subs)
     T_cap = _pow2(nnz + 6 * w + 8)
     for s in subs:
@@ -348,10 +378,22 @@ def _decompose_bucket_jit(subs: list[np.ndarray], w: int):
                 >= _INT32_MAX:
             raise _BucketOverflow
     lanes = _chunk_lanes(w, T_cap)
+    if intervals:
+        lanes = min(lanes, _fixed_lanes(w))
     out = []
     for lo in range(0, len(subs), lanes):
-        out.extend(_decompose_chunk(subs[lo:lo + lanes], w, T_cap))
+        out.extend(_decompose_chunk(subs[lo:lo + lanes], w, T_cap,
+                                    intervals, lanes))
     return out
+
+
+def _fixed_lanes(w: int) -> int:
+    """Lanes of a fixed-lane program at width w: a power of two whose
+    int32 demand stack fits ``_FIXED_STEP_BYTES``, at most
+    ``_FIXED_LANES_MAX``."""
+    per_lane = w * w * 4
+    fit = 1 << max((_FIXED_STEP_BYTES // per_lane).bit_length() - 1, 0)
+    return min(fit, _FIXED_LANES_MAX)
 
 
 def _chunk_lanes(w: int, T_cap: int) -> int:
@@ -361,9 +403,10 @@ def _chunk_lanes(w: int, T_cap: int) -> int:
     return 1 << max((_PIECES_BYTES // per_lane).bit_length() - 1, 0)
 
 
-def _decompose_chunk(subs: list[np.ndarray], w: int, T_cap: int):
+def _decompose_chunk(subs: list[np.ndarray], w: int, T_cap: int,
+                     intervals: bool, lanes: int):
     B = len(subs)
-    B_pad = _pow2(B)
+    B_pad = lanes if intervals else _pow2(B)
     d = np.zeros((B_pad, w, w), np.int32)
     ks = np.zeros(B_pad, np.int32)
     for i, s in enumerate(subs):
@@ -375,9 +418,15 @@ def _decompose_chunk(subs: list[np.ndarray], w: int, T_cap: int):
 
     avals = (jax.ShapeDtypeStruct((B_pad, w, w), np.int32),
              jax.ShapeDtypeStruct((B_pad,), np.int32))
-    fn = _get_compiled(("bna", B_pad, w, T_cap),
-                       lambda: _build_decompose(w, T_cap), avals)
-    count_launch("decompose")
+    if intervals:
+        fn = _get_compiled(("bna_intervals", B_pad, w, T_cap),
+                           lambda: _build_decompose_intervals(w, T_cap),
+                           avals)
+        count_launch("decompose_intervals")
+    else:
+        fn = _get_compiled(("bna", B_pad, w, T_cap),
+                           lambda: _build_decompose(w, T_cap), avals)
+        count_launch("decompose")
     ts, pieces, D_end = (np.asarray(x) for x in fn(d, ks))
     if D_end.any():
         raise AssertionError("jitted BNA failed to terminate (bug)")
@@ -417,37 +466,55 @@ def _plan_decompositions(demands: list[np.ndarray]):
     edge intervals of the coflow's isolated schedule anchored at 0."""
     with span("plan.decompose", coflows=len(demands)):
         _counters["batches"] += 1
-        out_p: list = [None] * len(demands)
-        out_e: list = [None] * len(demands)
-        buckets: dict[int, list] = {}
-        for i, dem in enumerate(demands):
-            d_full = np.asarray(dem, dtype=np.int64)
-            sub, rows_p, cols_p = support_restrict(d_full)
-            if sub is None:
-                z = np.zeros(0, np.int64)
-                out_p[i] = []
-                out_e[i] = (z, z.copy(), z.copy(), z.copy())
-                continue
-            w = bucket_width(sub.shape[0])
-            buckets.setdefault(w, []).append(
-                (i, sub, rows_p, cols_p, d_full.shape[0]))
-        for w in sorted(buckets):
-            items = buckets[w]
-            subs = [it[1] for it in items]
-            try:
-                res = _decompose_bucket_jit(subs, w)
-            except _BucketOverflow:
-                res = _decompose_bucket_py(subs, w)
-            for (i, _sub, rows_p, cols_p, m_full), (plist, rel) in \
-                    zip(items, res):
-                if rows_p is None:
-                    out_p[i] = plist
-                    out_e[i] = rel
-                else:
-                    out_p[i] = expand_pieces(plist, rows_p, cols_p, m_full)
-                    t0, t1, ss, rr = rel
-                    out_e[i] = (t0, t1, rows_p[ss], cols_p[rr])
-        return out_p, out_e
+        return _decompose_buckets(demands, intervals=False)
+
+
+def _decompose_buckets(demands: list[np.ndarray], intervals: bool):
+    """``_plan_decompositions``' work, bucket by bucket; ``intervals``: see
+    ``_decompose_bucket_jit``."""
+    out_p: list = [None] * len(demands)
+    out_e: list = [None] * len(demands)
+    buckets: dict[int, list] = {}
+    for i, dem in enumerate(demands):
+        d_full = np.asarray(dem, dtype=np.int64)
+        sub, rows_p, cols_p = support_restrict(d_full)
+        if sub is None:
+            z = np.zeros(0, np.int64)
+            out_p[i] = []
+            out_e[i] = (z, z.copy(), z.copy(), z.copy())
+            continue
+        w = bucket_width(sub.shape[0])
+        if intervals:
+            w = max(w, _FIXED_MIN_WIDTH)
+        buckets.setdefault(w, []).append(
+            (i, sub, rows_p, cols_p, d_full.shape[0]))
+    for w in sorted(buckets):
+        items = buckets[w]
+        subs = [it[1] for it in items]
+        try:
+            res = _decompose_bucket_jit(subs, w, intervals)
+        except _BucketOverflow:
+            res = _decompose_bucket_py(subs, w)
+        for (i, _sub, rows_p, cols_p, m_full), (plist, rel) in \
+                zip(items, res):
+            if rows_p is None:
+                out_p[i] = plist
+                out_e[i] = rel
+            else:
+                out_p[i] = expand_pieces(plist, rows_p, cols_p, m_full)
+                t0, t1, ss, rr = rel
+                out_e[i] = (t0, t1, rows_p[ss], cols_p[rr])
+    return out_p, out_e
+
+
+def decompose_pieces(demands: list[np.ndarray]) -> list:
+    """Full-m BNA pieces per merged-interval matrix through the compiled
+    buckets, bit-identical to ``bna.bna``: the interval programs, one per
+    (w, T_cap), and no ``plan.decompose`` span (the caller's
+    ``plan.nested_decompose`` holds the time); nothing is cached here (the
+    caller, ``backend.bna_pieces_many``, keeps the BNA cache)."""
+    return _decompose_buckets([np.asarray(d, np.int64) for d in demands],
+                              intervals=True)[0]
 
 
 # --------------------------------------------------------------------------

@@ -42,7 +42,8 @@ __all__ = ["NAMES", "Span", "Compile", "Recorder", "span", "annotate",
 # the stable span names, one per layer boundary (PERF.md, "Layers")
 NAMES = ("stream.feed", "session.replan", "session.repair", "session.plan",
          "plan.order", "plan.group", "plan.group_block", "plan.decompose",
-         "plan.merge_fix", "plan.nested_decompose")
+         "plan.merge_fix", "plan.nested_decompose", "plan.coflow_intervals",
+         "plan.backfill")
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"

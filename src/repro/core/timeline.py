@@ -338,8 +338,10 @@ class FinalSchedule:
             if self.merged is None:
                 raise ValueError("coflow_intervals requires the merged edge "
                                  "intervals (schedule predates merge_and_fix)")
-            _, _, self.coflow_edges = _decompose(
-                self.events, self.merged, self.alphas, self.exp, self.m)
+            with span("plan.coflow_intervals") as sp:
+                _, _, self.coflow_edges = _decompose(
+                    self.events, self.merged, self.alphas, self.exp, self.m)
+                sp.set(segments=self.coflow_edges.size)
         return self.coflow_edges
 
     # --- expansion splicing (session plan repair) ---------------------------
@@ -600,6 +602,11 @@ def merge_and_fix(
         return sched
 
 
+#: interval matrices sent to the batched BNA per call: at m = 150 one dense
+#: int64 matrix is 180 KB, so a chunk holds at most 46 MB of them
+_DECOMPOSE_CHUNK = 256
+
+
 def _decompose(
     events: np.ndarray, edges: EdgeIntervals, alphas: np.ndarray,
     exp: np.ndarray, m: int,
@@ -618,11 +625,18 @@ def _decompose(
     equals the coflow's demand on that edge, and at any instant the active
     segments form a matching.
 
-    Fast path: alpha_I == 1 means the merged active edges already form a
-    matching — emit directly without BNA."""
-    from .bna import bna
+    Two phases per chunk of ``_DECOMPOSE_CHUNK`` alpha_I > 1 intervals: the
+    activation walk collects each interval's FIFO queues and merged matrix,
+    then ``backend.bna_pieces_many`` decomposes the chunk's matrices in one
+    batch (the BNA cache first, each distinct matrix once, the compiled
+    buckets under the jit plan backend) and the FIFO walk runs over the
+    collected intervals in their original order.  The pieces are those of
+    ``bna.bna`` on each matrix, so the output is the interval-at-a-time
+    walk's, bit for bit.  Fast path: alpha_I == 1 means the merged active
+    edges already form a matching — emit directly without BNA."""
+    from .backend import _bna_key, bna_pieces_many
 
-    with span("plan.nested_decompose"):
+    with span("plan.nested_decompose") as sp:
         pieces: list[DecompPiece] = []
         completion: dict[int, float] = {}
         seg_t0: list[int] = []
@@ -654,8 +668,75 @@ def _decompose(
                 np.asarray(seg_cid, dtype=np.int64),
             )
 
+        def walk(iv, bna_out) -> None:
+            """The FIFO walk of one collected interval; ``bna_out`` is its
+            matrix's pieces (None when alpha_I <= 1)."""
+            t_exp, l, a, srcs, dsts, queues = iv
+            if bna_out is None:
+                pieces.append(DecompPiece(t_exp, l, srcs, dsts,
+                                          np.ones_like(srcs)))
+                end = float(t_exp + l)
+                for key, q in queues.items():
+                    cursor = t_exp
+                    for k3, amt in q:
+                        emit_seg(cursor, cursor + amt, key[0], key[1], k3)
+                        cursor += amt
+                        completion[k3[0]] = max(completion.get(k3[0], 0.0),
+                                                end)
+                return
+            off = 0
+            for dur, match in bna_out:
+                ss = np.flatnonzero(match >= 0)
+                pieces.append(DecompPiece(t_exp + off, int(dur), ss, match[ss],
+                                          np.ones(ss.size, dtype=np.int64)))
+                piece_end = float(t_exp + off + int(dur))
+                for s_ in ss:
+                    key = (int(s_), int(match[s_]))
+                    q = queues.get(key)
+                    if not q:
+                        continue
+                    served = int(dur)
+                    used = 0
+                    while served > 0 and q:
+                        k3, rem = q[0]
+                        take = min(rem, served)
+                        rem -= take
+                        served -= take
+                        emit_seg(t_exp + off + used, t_exp + off + used + take,
+                                 key[0], key[1], k3)
+                        used += take
+                        if rem == 0:
+                            q.pop(0)
+                        else:
+                            q[0][1] = rem
+                        completion[k3[0]] = max(completion.get(k3[0], 0.0),
+                                                piece_end)
+                off += int(dur)
+            assert off == l * a, "fix-up BNA length mismatch"
+
         if edges.size == 0:
+            sp.set(intervals=0, batched=0, distinct=0)
             return pieces, completion, pack()
+
+        # the intervals since the chunk's first matrix, in order, and the
+        # alpha_I > 1 matrices among them with their BNA cache keys
+        collected: list[tuple] = []
+        mats: list[np.ndarray] = []
+        keys: list[tuple] = []
+        batched = 0
+        distinct: set[int] = set()
+
+        def flush() -> None:
+            nonlocal batched
+            out = iter(bna_pieces_many(mats, keys=keys) if mats else ())
+            for iv in collected:
+                walk(iv, next(out) if iv[2] > 1 else None)
+            batched += len(mats)
+            distinct.update(hash(k) for k in keys)
+            collected.clear()
+            mats.clear()
+            keys.clear()
+
         K = alphas.size
         si = np.searchsorted(events, edges.t0)
         ei = np.searchsorted(events, edges.t1)
@@ -701,51 +782,23 @@ def _decompose(
             a = int(alphas[k])
             srcs = np.array([s for s, _ in active], dtype=np.int64)
             dsts = np.array([r for _, r in active], dtype=np.int64)
-            cnts = np.array([sum(e[2] for e in lst) for lst in active.values()],
-                            dtype=np.int64)
             # FIFO queues for this interval: per edge, units in activation order
             queues = {key: [[k3, mult * l] for _, k3, mult in sorted(lst)]
                       for key, lst in active.items()}
-            if a <= 1:
-                pieces.append(DecompPiece(t_exp, l, srcs, dsts,
-                                          np.ones_like(cnts)))
-                end = float(t_exp + l)
-                for key, q in queues.items():
-                    cursor = t_exp
-                    for k3, amt in q:
-                        emit_seg(cursor, cursor + amt, key[0], key[1], k3)
-                        cursor += amt
-                        completion[k3[0]] = max(completion.get(k3[0], 0.0), end)
+            iv = (t_exp, l, a, srcs, dsts, queues)
+            if a <= 1 and not mats:
+                walk(iv, None)      # nothing pending before it
                 continue
-            dm = np.zeros((m, m), dtype=np.int64)
-            dm[srcs, dsts] = cnts * l
-            off = 0
-            for dur, match in bna(dm):
-                ss = np.flatnonzero(match >= 0)
-                pieces.append(DecompPiece(t_exp + off, int(dur), ss, match[ss],
-                                          np.ones(ss.size, dtype=np.int64)))
-                piece_end = float(t_exp + off + int(dur))
-                for s_ in ss:
-                    key = (int(s_), int(match[s_]))
-                    q = queues.get(key)
-                    if not q:
-                        continue
-                    served = int(dur)
-                    used = 0
-                    while served > 0 and q:
-                        k3, rem = q[0]
-                        take = min(rem, served)
-                        rem -= take
-                        served -= take
-                        emit_seg(t_exp + off + used, t_exp + off + used + take,
-                                 key[0], key[1], k3)
-                        used += take
-                        if rem == 0:
-                            q.pop(0)
-                        else:
-                            q[0][1] = rem
-                        completion[k3[0]] = max(completion.get(k3[0], 0.0),
-                                                piece_end)
-                off += int(dur)
-            assert off == l * a, "fix-up BNA length mismatch"
+            collected.append(iv)
+            if a > 1:
+                cnts = np.array([sum(e[2] for e in lst)
+                                 for lst in active.values()], dtype=np.int64)
+                dm = np.zeros((m, m), dtype=np.int64)
+                dm[srcs, dsts] = cnts * l
+                mats.append(dm)
+                keys.append(_bna_key(dm))
+                if len(mats) == _DECOMPOSE_CHUNK:
+                    flush()
+        flush()
+        sp.set(intervals=K, batched=batched, distinct=len(distinct))
         return pieces, completion, pack()

@@ -23,12 +23,13 @@ the scheduler kernels compile for a v5e is checked without a chip by
 
 Every launch of a scheduler device program is counted here, at the one
 place that launches it (``count_launch``): the Pallas kernels after their
-int32 range guards, and the jitted planning pipeline's ``decompose`` and
-``loads`` programs.  ``repro.core.cache_stats()["launches"]`` reports the
+int32 range guards, and the jitted planning pipeline's ``decompose``,
+``decompose_intervals`` and ``loads`` programs.  ``repro.core.cache_stats()["launches"]`` reports the
 counts, so a run shows which device paths did its work.
 """
 
-_launches = {"coflow_merge": 0, "bna_step": 0, "decompose": 0, "loads": 0}
+_launches = {"coflow_merge": 0, "bna_step": 0, "decompose": 0,
+             "decompose_intervals": 0, "loads": 0}
 
 
 def count_launch(name: str) -> None:

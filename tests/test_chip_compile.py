@@ -121,3 +121,19 @@ def test_pipeline_loads_compiles_for_v5e(one_chip):
 
     _compile(pipeline._build_loads(150, 64),
              _i32((512, 150, 150), one_chip), _i32((512,), one_chip))
+
+
+@pytest.mark.parametrize("w", [32, 256])
+def test_fixed_lane_interval_programs_fit_one_v5e(one_chip, w):
+    """The merged-interval batches of ``timeline._decompose`` run on
+    fixed-lane programs, one per (w, T_cap): their demand stacks stay
+    within ``_FIXED_STEP_BYTES`` and they compile for the chip."""
+    from repro.core import pipeline
+
+    lanes = pipeline._fixed_lanes(w)
+    T_cap = pipeline._pow2(4 * w + 6 * w + 8)
+    assert lanes * w * w * 4 <= pipeline._FIXED_STEP_BYTES
+    assert lanes <= pipeline._chunk_lanes(w, T_cap)
+    _, total = _compile(pipeline._build_decompose_intervals(w, T_cap),
+                        _i32((lanes, w, w), one_chip), _i32((lanes,), one_chip))
+    assert total < pipeline._PIECES_BYTES

@@ -15,8 +15,8 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import (StreamDriver, clear_caches, run_stream, spans,
-                        stream_jobs)
+from repro.core import (StreamDriver, backfill, clear_caches, paper_workload,
+                        plan, run_stream, spans, stream_jobs)
 
 SRC = Path(__file__).resolve().parents[1] / "src" / "repro"
 
@@ -198,6 +198,46 @@ def test_completions_equal_with_recording_on_and_off(scheduler):
     assert on.online.twct() == off.online.twct()
 
 
+def test_backfill_records_its_spans_with_their_attributes():
+    """``plan.backfill`` holds the lazy ``plan.coflow_intervals`` builds,
+    each holding its ``plan.nested_decompose``; the attributes count what
+    each layer did."""
+    inst = paper_workload(m=12, mu_bar=4, seed=4, scale=0.05)
+    clear_caches()
+    sched = plan(inst, "gdm", delays="spread", seed=0).schedule
+    with spans.recording() as rec:
+        bf = backfill(sched)
+    by_name = {}
+    for i, s in enumerate(rec.spans):
+        by_name.setdefault(s.name, []).append(i)
+    (b,) = by_name["plan.backfill"]
+    assert rec.spans[b].parent is None
+    built = by_name["plan.coflow_intervals"]
+    assert len(built) == len(sched.parts)
+    assert all(rec.spans[i].parent == b for i in built)
+    nested = by_name["plan.nested_decompose"]
+    assert [rec.spans[i].parent for i in nested] == built
+    segments = [rec.spans[i].attrs["segments"] for i in built]
+    assert segments == [p.coflow_intervals().size for p in sched.parts]
+    attrs = rec.spans[b].attrs
+    assert attrs["segments"] == sum(segments)
+    assert attrs["intervals"] > 0 and attrs["fills"] > 0
+    assert bf.twct() < sched.twct()
+    for i in nested:
+        a = rec.spans[i].attrs
+        assert a["intervals"] > 0
+        assert 0 <= a["distinct"] <= a["batched"] <= a["intervals"]
+    assert sum(rec.spans[i].attrs["batched"] for i in nested) > 0
+    # a second call reads the built intervals: no new build, no span
+    with spans.recording() as again:
+        sched.parts[0].coflow_intervals()
+    assert again.spans == []
+    with spans.recording() as unfilled:
+        backfill(sched, fill=False)
+    (b,) = [s for s in unfilled.spans if s.name == "plan.backfill"]
+    assert b.attrs["fills"] == 0 and b.attrs["segments"] == attrs["segments"]
+
+
 # --- where the spans sit ----------------------------------------------------
 
 # (module, function, span) for every span the program opens
@@ -209,10 +249,12 @@ WHERE = {
     ("core/ordering.py", "cached_job_order", "plan.order"),
     ("core/gdm.py", "group_jobs", "plan.group"),
     ("core/backend.py", "group_block", "plan.group_block"),
-    ("core/backend.py", "bna_pieces_many", "plan.decompose"),
+    ("core/backend.py", "prefetch_bna", "plan.decompose"),
     ("core/pipeline.py", "_plan_decompositions", "plan.decompose"),
     ("core/timeline.py", "merge_and_fix", "plan.merge_fix"),
     ("core/timeline.py", "_decompose", "plan.nested_decompose"),
+    ("core/timeline.py", "coflow_intervals", "plan.coflow_intervals"),
+    ("core/backfill.py", "backfill", "plan.backfill"),
 }
 
 
